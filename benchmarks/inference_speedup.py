@@ -19,6 +19,7 @@ from benchmarks.common import spc_with_retrain, Timer
 from repro.core.metrics import model_size_bytes
 from repro.models.cnn import CNN_ZOO
 from repro.roofline.analysis import HBM_BW
+from repro.sparse.compress import CompressionPlan
 from repro.sparse.formats import dense_to_bcsr, dense_to_csr
 
 STEPS = 250
@@ -94,7 +95,7 @@ def run(steps: int = STEPS):
                              f"nnz_frac={csr.nnz/w.size:.4f}")})
 
     # derived TPU memory-bound time: HBM bytes for dense vs BCSR weights
-    bcsr = dense_to_bcsr(w, block=(8, 128))
+    bcsr = dense_to_bcsr(w, block=CompressionPlan.block)
     dense_bytes = w.size * 4 + x.size * 4
     bcsr_bytes = bcsr.nbytes + x.size * 4
     rows.append({"name": "inference_speedup/tpu_roofline_derived",
@@ -117,13 +118,14 @@ def decode_compressed_row(gen_steps: int = 8):
 
     from repro.models.model_zoo import build
     from repro.serve.step import generate
-    from repro.sparse.compress import (CompressionPlan, compress_params,
+    from repro.sparse.compress import (compress_params,
                                        compressed_size_bytes,
                                        prune_blocks_for_plan,
                                        quantize_compressed)
 
     model = build("smollm-360m", reduced=True)
     params = model.init(jax.random.PRNGKey(0))
+    # (8, 64): the reduced widths would be one (128, 128) block per matrix
     plan = CompressionPlan(block=(8, 64), min_sparsity=0.5)
     pruned = prune_blocks_for_plan(params, plan, 0.85)
     cp = compress_params(pruned, plan)

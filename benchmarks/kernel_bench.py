@@ -43,7 +43,7 @@ from repro.kernels.paged_attention import ops as paged_ops
 from repro.kernels.paged_attention import ref as paged_ref
 from repro.kernels.prox_adam import ops as prox_ops
 from repro.roofline.analysis import HBM_BW
-from repro.sparse.compress import quantize_bcsr
+from repro.sparse.compress import CompressionPlan, quantize_bcsr
 from repro.sparse.formats import dense_to_bcsr
 
 PARITY_TOL = 1e-4
@@ -77,8 +77,8 @@ def _paged_scenario(rng, b, c, ctx):
     p_log = -(-(ctx + c) // ps)
     n_pages = 1 + b * p_log
     q = jnp.asarray(rng.normal(size=(b, c, h, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, ps, kv, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, ps, kv, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(n_pages, kv, ps, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(n_pages, kv, ps, hd)), jnp.float32)
     table = jnp.asarray(
         1 + np.arange(b * p_log, dtype=np.int32).reshape(b, p_log))
     start = np.full(b, ctx, np.int32)
@@ -118,7 +118,7 @@ def _paged_row(name, rng, b, c, ctx, iters):
 # -- BCSR / palette spmm ----------------------------------------------------
 
 def _spmm_row(name, rng, sparsity, iters, bits=0):
-    m_rows, n, k, bl = 64, 512, 512, (8, 64)
+    m_rows, n, k, bl = 64, 512, 512, CompressionPlan.block
     w = _block_sparse(rng, n, k, bl, sparsity)
     mat = dense_to_bcsr(w, bl)
     x = jnp.asarray(rng.normal(size=(m_rows, k)), jnp.float32)
@@ -147,7 +147,7 @@ def _spmm_row(name, rng, sparsity, iters, bits=0):
 
 
 def _sddmm_row(name, rng, sparsity, iters):
-    m_rows, n, k, bl = 64, 512, 512, (8, 64)
+    m_rows, n, k, bl = 64, 512, 512, CompressionPlan.block
     w = _block_sparse(rng, n, k, bl, sparsity)
     mat = dense_to_bcsr(w, bl)
     x = jnp.asarray(rng.normal(size=(m_rows, k)), jnp.float32)

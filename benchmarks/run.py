@@ -49,6 +49,7 @@ def main() -> None:
     import importlib
     print("name,us_per_call,derived")
     t_all = time.time()
+    failed = []
     for modname in MODULES:
         try:
             mod = importlib.import_module(modname)
@@ -58,9 +59,12 @@ def main() -> None:
                 sys.stdout.flush()
         except Exception as e:  # noqa: BLE001
             print(f"{modname},0,\"ERROR:{type(e).__name__}:{e}\"")
+            failed.append(modname)
     for row in dryrun_rows():
         print(f"{row['name']},{row['us_per_call']:.2f},\"{row['derived']}\"")
     print(f"# total wall time: {time.time()-t_all:.1f}s")
+    if failed:
+        sys.exit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -369,6 +369,9 @@ def run():
 
     model = build("smollm-360m", reduced=True)
     params = model.init(jax.random.PRNGKey(0))
+    # the reduced widths (d_model 64, d_ff 128) would be one (128, 128)
+    # serving block per matrix, pruned whole; (8, 64) gives a real block grid
+    # on the CPU (interpret/ref kernels take any block)
     plan = CompressionPlan(block=(8, 64), min_sparsity=0.5)
     pruned = prune_blocks_for_plan(params, plan, 0.85)
     cp = compress_params(pruned, plan)

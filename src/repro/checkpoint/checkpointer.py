@@ -49,6 +49,10 @@ _INDEX_FIELDS = ("col_idx", "row_ptr",
                  "gather_t_idx", "gather_t_blk", "gather_t_nnz")
 _BCSR_FIELDS = ("data",) + _INDEX_FIELDS
 _PBCSR_FIELDS = ("codes", "palette") + _INDEX_FIELDS
+# 4-bit codes' nibble packing (formats.pack_uint4): "split" puts codes[k] and
+# codes[k + n/2] in byte k. Entries without the marker were written with the
+# older interleaved packing (codes[2k], codes[2k+1]) and are repacked on load.
+_NIBBLES = "split"
 
 
 def _is_bcsr(x) -> bool:
@@ -101,6 +105,8 @@ class Checkpointer:
                              leaf.palette if quant else leaf.data).dtype)}
                 if quant:
                     entry["bits"] = int(leaf.bits)
+                    if leaf.bits == 4:
+                        entry["nibbles"] = _NIBBLES
                 for f in fields:
                     arrays[f"{name}__{f}"] = np.asarray(
                         jax.device_get(getattr(leaf, f)))
@@ -294,12 +300,28 @@ def _pbcsr_restore(npz, name, entry) -> PaletteBCSR:
     """Rebuild a PaletteBCSR leaf from its stored arrays — codes stay
     quantized (and nibble-packed at 4 bits) from disk into serving memory."""
     import jax.numpy as jnp
-    arrs = {f: jnp.asarray(npz[f"{name}__{f}".replace("/", "|")])
-            for f in _PBCSR_FIELDS}
+    arrs = {f: npz[f"{name}__{f}".replace("/", "|")] for f in _PBCSR_FIELDS}
+    if int(entry["bits"]) == 4:
+        nibbles = entry.get("nibbles", "interleaved")
+        if nibbles == "interleaved":
+            arrs["codes"] = _split_nibbles(arrs["codes"])
+        elif nibbles != _NIBBLES:
+            raise ValueError(f"{name}: unknown 4-bit nibble packing "
+                             f"{nibbles!r}")
+    arrs = {f: jnp.asarray(a) for f, a in arrs.items()}
     return PaletteBCSR(shape=tuple(entry["shape"]),
                        block=tuple(entry["block"]),
                        n_blocks=int(entry["n_blocks"]),
                        bits=int(entry["bits"]), **arrs)
+
+
+def _split_nibbles(packed: np.ndarray) -> np.ndarray:
+    """Repack interleaved 4-bit codes (byte k = codes[2k] | codes[2k+1] << 4)
+    into the split packing of ``formats.pack_uint4``."""
+    codes = np.stack([packed & 0xF, packed >> 4], axis=-1).reshape(
+        *packed.shape[:-1], 2 * packed.shape[-1])
+    half = packed.shape[-1]
+    return (codes[..., :half] | (codes[..., half:] << 4)).astype(np.uint8)
 
 
 def _csr_restore(npz, name, shape, dtype):

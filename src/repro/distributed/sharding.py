@@ -92,6 +92,21 @@ def current_mesh() -> Optional[Mesh]:
     return st[0] if st else None
 
 
+def run_replicated(fn, *args):
+    """Call ``fn(*args)``, a Pallas kernel, under the ambient mesh.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so under ``use_mesh`` the call runs inside
+    a ``shard_map`` with every operand and result replicated: the compiler
+    all-gathers sharded operands in front of the kernel and every device
+    runs it on the whole problem. Without a mesh this is a plain call."""
+    mesh = current_mesh()
+    if mesh is None:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
+
+
 def _axes_to_spec(logical: Sequence[Optional[str]], shape, mesh: Mesh,
                   rules: dict) -> P:
     """Two-pass assignment: primary logical axes claim mesh axes first;

@@ -23,9 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.bsr_spmm.bsr_spmm import check_block_tiling
 
 
 def _kernel(rows_ref, cols_ref, dy_ref, x_ref, out_ref, *, n_m):
@@ -49,6 +47,7 @@ def sddmm_block_grad(dy, x, slot_rows, slot_cols, n_slots: int,
     slot_rows/slot_cols: int32[n_slots] block coordinates per slot (slot 0
     is the BCSR pad slot; the wrapper zeroes its output).
     """
+    check_block_tiling((br, bc), interpret)
     m_dim = dy.shape[0]
     assert m_dim % bm == 0 and m_dim == x.shape[0]
     grid = (n_slots, m_dim // bm)
@@ -74,7 +73,8 @@ def sddmm_block_grad(dy, x, slot_rows, slot_cols, n_slots: int,
             out_specs=pl.BlockSpec((1, br, bc), out_map),
         ),
         out_shape=jax.ShapeDtypeStruct((n_slots, br, bc), out_dtype),
-        compiler_params=_CompilerParams(
+        name="bsr_sddmm",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(slot_rows, slot_cols, dy, x)

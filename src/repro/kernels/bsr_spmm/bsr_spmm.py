@@ -42,9 +42,26 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.sparse.formats import unpack_uint4
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+LANES = 128
+NAME = "bsr_spmm"      # kernel_name in HLO and profiler traces
+
+
+def check_block_tiling(block, interpret: bool) -> None:
+    """Mosaic's tiling rule for the compiled kernels: the last two dims of
+    every BlockSpec are multiples of (8, 128) or whole array dims. The
+    dense operand and output tiles are ``(bm, br)`` / ``(bm, bc)`` slices of
+    wider arrays, so both block dims must be multiples of 128. Interpret
+    mode has no such rule; the compiled path raises here, at trace time,
+    instead of failing inside Mosaic or falling back to another backend."""
+    if interpret:
+        return
+    br, bc = block
+    if br % LANES or bc % LANES:
+        raise ValueError(
+            f"BCSR block {tuple(block)} cannot be tiled by the compiled "
+            f"Pallas TPU kernel: Mosaic needs the last two block dims to be "
+            f"multiples of (8, {LANES}), so both block dims must be "
+            f"multiples of {LANES} (use --block 128 128)")
 
 
 def _kernel(nnz_ref, idx_ref, blk_ref,     # scalar-prefetch (SMEM)
@@ -81,7 +98,7 @@ def _palette_kernel(nnz_ref, idx_ref, blk_ref,   # scalar-prefetch (SMEM)
     @pl.when(j < nnz_ref[o])
     def _acc():
         d = d_ref[...]
-        codes = c_ref[0]                     # (br, bc) or (br, bc//2) uint8
+        codes = c_ref[0].astype(jnp.int32)   # (br, bc) or (br, bc//2)
         if bits == 4:
             codes = unpack_uint4(codes)      # pure jnp — one shared copy of
                                              # the nibble-ordering convention
@@ -89,7 +106,7 @@ def _palette_kernel(nnz_ref, idx_ref, blk_ref,   # scalar-prefetch (SMEM)
         # matvec instead of a vector gather (which Mosaic lacks); code 0 hits
         # palette[0] == 0 so intra-block zeros and the pad slot stay exact
         palette = p_ref[0].astype(jnp.float32)      # (P,)
-        onehot = jax.nn.one_hot(codes.astype(jnp.int32), palette.shape[0],
+        onehot = jax.nn.one_hot(codes, palette.shape[0],
                                 dtype=jnp.float32)  # (br, bc, P)
         w = jax.lax.dot_general(onehot, palette,
                                 (((2,), (0,)), ((), ())))
@@ -111,12 +128,13 @@ def gather_block_matmul_palette(dense, codes, palette, idx, blk, nnz, *,
     store holds uint8 codes and the fp32 palette is dequantized in-kernel.
 
     codes   : (n_slots, br, bc) uint8 at bits=8, (n_slots, br, bc//2) at
-              bits=4 (two nibble codes per byte, low nibble first)
+              bits=4 (two nibble codes per byte, ``formats.pack_uint4``)
     palette : (P,) fp32 with palette[0] == 0 (P = 2**bits)
     """
     M, Kin = dense.shape
     n_slots, br, bcs = codes.shape
     bc = bcs * 2 if bits == 4 else bcs
+    check_block_tiling((br, bc), interpret)
     O, jmax = idx.shape
     b_in, b_out = (bc, br) if transpose_block else (br, bc)
     assert Kin % b_in == 0 and out_cols % b_out == 0 and M % bm == 0, (
@@ -154,7 +172,8 @@ def gather_block_matmul_palette(dense, codes, palette, idx, blk, nnz, *,
             out_specs=pl.BlockSpec((bm, b_out), o_map),
         ),
         out_shape=jax.ShapeDtypeStruct((M, out_cols), out_dtype),
-        compiler_params=_CompilerParams(
+        name="bsr_spmm_palette",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(nnz, idx, blk, dense, codes, pal2d)
@@ -178,6 +197,7 @@ def gather_block_matmul(dense, data, idx, blk, nnz, *,
     """
     M, Kin = dense.shape
     n_slots, br, bc = data.shape
+    check_block_tiling((br, bc), interpret)
     O, jmax = idx.shape
     b_in, b_out = (bc, br) if transpose_block else (br, bc)
     assert Kin % b_in == 0 and out_cols % b_out == 0 and M % bm == 0, (
@@ -209,7 +229,8 @@ def gather_block_matmul(dense, data, idx, blk, nnz, *,
             out_specs=pl.BlockSpec((bm, b_out), o_map),
         ),
         out_shape=jax.ShapeDtypeStruct((M, out_cols), out_dtype),
-        compiler_params=_CompilerParams(
+        name=NAME,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(nnz, idx, blk, dense, data)

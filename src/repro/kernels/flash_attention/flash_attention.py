@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
 
 
@@ -105,7 +101,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None,
         scratch_shapes=[pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -37,27 +37,29 @@ from repro.obs.profile import kernel_call
 def _paged_flash_attention(q, k_pool, v_pool, page_table, positions, *,
                            window=None, kv_splits: int = 1, interpret=None):
     b, c, h, hd = q.shape
-    kv = k_pool.shape[2]
+    kv = k_pool.shape[1]
     g = h // kv
-    qg = q.reshape(b, c, kv, g, hd).transpose(0, 2, 1, 3, 4)
+    qg = q.reshape(b, c, kv, g, hd).transpose(0, 2, 1, 3, 4).reshape(
+        b, kv, c * g, hd)
     start = positions[:, 0]
     if interpret is None:
         interpret = use_interpret()
     acc, m, l = paged_flash_fwd(
-        qg.astype(jnp.float32), k_pool, v_pool, page_table, positions,
-        start, window=window, kv_splits=kv_splits, interpret=interpret)
+        qg.astype(jnp.float32), k_pool, v_pool, page_table, start, g=g,
+        window=window, kv_splits=kv_splits, interpret=interpret)
     # cross-split softmax combine (exact; identity at kv_splits == 1)
-    m_star = jnp.max(m, axis=2)                            # (B, KV, C, g)
-    w = jnp.exp(m - m_star[:, :, None])                    # (B, KV, S, C, g)
+    m_star = jnp.max(m, axis=2)                            # (B, KV, C*g, 1)
+    w = jnp.exp(m - m_star[:, :, None])                    # (B, KV, S, C*g, 1)
     l_tot = jnp.sum(w * l, axis=2)
-    acc_tot = jnp.sum(w[..., None] * acc, axis=2)
-    out = acc_tot / jnp.maximum(l_tot, 1e-30)[..., None]   # (B, KV, C, g, hd)
-    return out.transpose(0, 2, 1, 3, 4).reshape(b, c, h, hd)
+    acc_tot = jnp.sum(w * acc, axis=2)
+    out = acc_tot / jnp.maximum(l_tot, 1e-30)              # (B, KV, C*g, hd)
+    return out.reshape(b, kv, c, g, hd).transpose(0, 2, 1, 3, 4).reshape(
+        b, c, h, hd)
 
 
 def paged_flash_attention(q, k_pool, v_pool, page_table, positions, *,
                           window=None, kv_splits: int = 1, interpret=None):
-    """q: (B, C, H, hd); k/v_pool: (n_pages, ps, KV, hd);
+    """q: (B, C, H, hd); k/v_pool: (n_pages, KV, ps, hd) head-major pools;
     page_table: (B, P) int32; positions: (B, C) int32 ABSOLUTE positions —
     the engine contract ``positions = start_pos[:, None] + arange(C)``
     (the kernel's page-skip predicates assume row 0 is the tick start).
@@ -72,3 +74,4 @@ def paged_flash_attention(q, k_pool, v_pool, page_table, positions, *,
 
 
 paged_attention_ref = ref_lib.paged_attention_ref
+gather_pages = ref_lib.gather_pages

@@ -25,8 +25,15 @@ online-softmax recurrence (running max / sum / accumulator in VMEM), with
 
 Grid: (B, KV, S, PP) with the page axis innermost ('arbitrary'); B, kv
 head and split lanes are 'parallel'. Queries arrive pre-grouped as
-(B, KV, C, g, hd) — the g query heads sharing a kv head are flattened into
-the row axis of one (C*g, hd) x (hd, page_size) matmul per page.
+(B, KV, C*g, hd) — the g query heads sharing a kv head are flattened into
+the row axis (row r is token r // g) of one (C*g, hd) x (hd, page_size)
+matmul per page. Pools are head-major, (n_pages, KV, page_size, hd), so one
+grid step's K/V tile is a whole (page_size, hd) slab: every block's last
+two dims are whole array dims, which Mosaic's tiling rule (multiples of
+(8, 128) or whole dims) accepts at any page size, head width and head
+count. Query positions are not an operand: the engine contract is
+``positions = start + arange(C)``, so the kernel derives them from the
+scalar-prefetched ``start``.
 """
 from __future__ import annotations
 
@@ -37,22 +44,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
+NAME = "paged_flash_attention"   # kernel_name in HLO and profiler traces
 
 
 def _kernel(table_ref, start_ref,            # scalar-prefetch (SMEM)
-            q_ref, pos_ref, k_ref, v_ref,    # VMEM tiles
+            q_ref, k_ref, v_ref,             # VMEM tiles
             acc_out, m_out, l_out,           # unnormalized partials
             m_sc, l_sc, acc_sc,              # VMEM carries across pages
-            *, scale, window, ps, n_pages_per_split, n_logical_pages):
+            *, scale, window, ps, g, n_pages_per_split, n_logical_pages):
     b = pl.program_id(0)
     s = pl.program_id(2)
     j = pl.program_id(3)
-    c, g, hd = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
+    rows = q_ref.shape[2]                    # C * g
+    c = rows // g
 
     @pl.when(j == 0)
     def _init():
@@ -73,25 +78,25 @@ def _kernel(table_ref, start_ref,            # scalar-prefetch (SMEM)
 
     @pl.when(run)
     def _page():
-        q = q_ref[0, 0].reshape(c * g, hd).astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (ps, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)                # (C*g, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                # (ps, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
-        q_pos = pos_ref[0].reshape(c, 1)                   # absolute q pos
-        k_pos = page * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        mask = q_pos >= k_pos                              # (c, ps) causal
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 0)
+        q_pos = start + (row // g if g > 1 else row)       # absolute q pos
+        k_pos = page * ps + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
+        mask = q_pos >= k_pos                              # causal
         if window is not None:
             mask &= (q_pos - k_pos) < window
-        sc = jnp.where(mask[:, None, :], sc.reshape(c, g, ps),
-                       NEG_INF).reshape(c * g, ps)
+        sc = jnp.where(mask, sc, NEG_INF)
 
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1))
-        p = jnp.exp(sc - m_new[:, None])
+        m_prev = m_sc[...]                                 # (C*g, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        p = jnp.exp(sc - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_sc[...] = corr * l_sc[...] + jnp.sum(p, axis=1)
-        acc_sc[...] = corr[:, None] * acc_sc[...] + jax.lax.dot(
+        l_sc[...] = corr * l_sc[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_sc[...] = corr * acc_sc[...] + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
         m_sc[...] = m_new
 
@@ -100,39 +105,38 @@ def _kernel(table_ref, start_ref,            # scalar-prefetch (SMEM)
         # UNNORMALIZED partial per split lane: ops.paged_flash_attention
         # runs the cross-split combine. A lane whose pages were all skipped
         # emits (m=-inf, l=0, acc=0) and drops out of the combine.
-        m_out[0, 0, 0] = m_sc[...].reshape(c, g)
-        l_out[0, 0, 0] = l_sc[...].reshape(c, g)
-        acc_out[0, 0, 0] = acc_sc[...].reshape(c, g, hd)
+        m_out[0, 0, 0] = m_sc[...]
+        l_out[0, 0, 0] = l_sc[...]
+        acc_out[0, 0, 0] = acc_sc[...]
 
 
-def paged_flash_fwd(q, k_pool, v_pool, page_table, positions, start, *,
+def paged_flash_fwd(q, k_pool, v_pool, page_table, start, *, g: int,
                     window=None, kv_splits: int = 1,
                     interpret: bool = False):
     """Unnormalized flash-decode partials over a block-paged KV pool.
 
-    q          : (B, KV, C, g, hd) queries grouped per kv head
-    k/v_pool   : (n_pages, page_size, KV, hd) physical page pools
+    q          : (B, KV, C*g, hd) queries grouped per kv head (row r is
+                 token r // g of the tick)
+    k/v_pool   : (n_pages, KV, page_size, hd) head-major page pools
     page_table : (B, P) int32 — physical page of each slot's logical page
-    positions  : (B, C) int32 absolute positions (= start[:, None] + arange)
-    start      : (B,) int32 first absolute position of the tick
+    start      : (B,) int32 first absolute position of the tick (token t
+                 sits at start + t)
 
-    Returns (acc, m, l): acc (B, KV, S, C, g, hd) f32 and m/l
-    (B, KV, S, C, g) f32 — per-KV-split running max / sum / accumulator,
+    Returns (acc, m, l): acc (B, KV, S, C*g, hd) f32 and m/l
+    (B, KV, S, C*g, 1) f32 — per-KV-split running max / sum / accumulator,
     to be combined by the caller (S = kv_splits).
     """
-    b, kv, c, g, hd = q.shape
-    n_pages, ps = k_pool.shape[0], k_pool.shape[1]
+    b, kv, rows, hd = q.shape
+    ps = k_pool.shape[2]
     n_logical = page_table.shape[1]
     s_lanes = int(kv_splits)
     assert 1 <= s_lanes <= n_logical, (kv_splits, n_logical)
+    assert rows % g == 0, (rows, g)
     pp = -(-n_logical // s_lanes)            # pages per split lane (ceil)
     scale = hd ** -0.5
 
     def q_map(bi, ki, si, ji, table_s, start_s):
-        return (bi, ki, 0, 0, 0)
-
-    def pos_map(bi, ki, si, ji, table_s, start_s):
-        return (bi, 0)
+        return (bi, ki, 0, 0)
 
     def kv_map(bi, ki, si, ji, table_s, start_s):
         # THE gather: the page axis of the pool is indexed through the
@@ -140,16 +144,13 @@ def paged_flash_fwd(q, k_pool, v_pool, page_table, positions, start, *,
         # DMA'd. Lanes past the table end (si*pp + ji >= P) clamp to a
         # valid entry; the kernel's `run` predicate ignores their tile.
         page = jnp.minimum(si * pp + ji, n_logical - 1)
-        return (table_s[bi, page], 0, ki, 0)
+        return (table_s[bi, page], ki, 0, 0)
 
     def out_map(bi, ki, si, ji, table_s, start_s):
-        return (bi, ki, si, 0, 0, 0)
-
-    def ml_map(bi, ki, si, ji, table_s, start_s):
         return (bi, ki, si, 0, 0)
 
     kern = functools.partial(_kernel, scale=scale, window=window, ps=ps,
-                             n_pages_per_split=pp,
+                             g=g, n_pages_per_split=pp,
                              n_logical_pages=n_logical)
     return pl.pallas_call(
         kern,
@@ -157,28 +158,28 @@ def paged_flash_fwd(q, k_pool, v_pool, page_table, positions, start, *,
             num_scalar_prefetch=2,
             grid=(b, kv, s_lanes, pp),
             in_specs=[
-                pl.BlockSpec((1, 1, c, g, hd), q_map),
-                pl.BlockSpec((1, c), pos_map),
-                pl.BlockSpec((1, ps, 1, hd), kv_map),
-                pl.BlockSpec((1, ps, 1, hd), kv_map),
+                pl.BlockSpec((1, 1, rows, hd), q_map),
+                pl.BlockSpec((1, 1, ps, hd), kv_map),
+                pl.BlockSpec((1, 1, ps, hd), kv_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, 1, c, g, hd), out_map),
-                pl.BlockSpec((1, 1, 1, c, g), ml_map),
-                pl.BlockSpec((1, 1, 1, c, g), ml_map),
+                pl.BlockSpec((1, 1, 1, rows, hd), out_map),
+                pl.BlockSpec((1, 1, 1, rows, 1), out_map),
+                pl.BlockSpec((1, 1, 1, rows, 1), out_map),
             ],
-            scratch_shapes=[pltpu.VMEM((c * g,), jnp.float32),
-                            pltpu.VMEM((c * g,), jnp.float32),
-                            pltpu.VMEM((c * g, hd), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, hd), jnp.float32)],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, kv, s_lanes, c, g, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, kv, s_lanes, c, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, kv, s_lanes, c, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, kv, s_lanes, rows, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, kv, s_lanes, rows, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, kv, s_lanes, rows, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        name=NAME,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), start.astype(jnp.int32),
-      q, positions.astype(jnp.int32), k_pool, v_pool)
+      q, k_pool, v_pool)

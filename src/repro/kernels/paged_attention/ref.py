@@ -16,9 +16,18 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
+def gather_pages(pool, page_table):
+    """(n_pages, KV, ps, hd) pool, (B, P) table -> the (B, P*ps, KV, hd)
+    gathered context of every slot, in position order."""
+    b, p_log = page_table.shape
+    _, kv, ps, hd = pool.shape
+    return pool[page_table].transpose(0, 1, 3, 2, 4).reshape(
+        b, p_log * ps, kv, hd)
+
+
 def paged_attention_ref(q, k_pool, v_pool, page_table, positions, *,
                         window: Optional[int] = None):
-    """q: (B, C, H, hd); k/v_pool: (n_pages, ps, KV, hd);
+    """q: (B, C, H, hd); k/v_pool: (n_pages, KV, ps, hd) head-major pools;
     page_table: (B, P) int32; positions: (B, C) int32 absolute positions.
 
     Returns (B, C, H, hd) f32. Rows whose query is invalid (an inactive
@@ -26,11 +35,11 @@ def paged_attention_ref(q, k_pool, v_pool, page_table, positions, *,
     kernel path — callers discard them downstream.
     """
     b, c, h, hd = q.shape
-    ps, kv = k_pool.shape[1], k_pool.shape[2]
+    kv, ps = k_pool.shape[1], k_pool.shape[2]
     p_log = page_table.shape[1]
     g = h // kv
-    k_ctx = k_pool[page_table].reshape(b, p_log * ps, kv, hd)
-    v_ctx = v_pool[page_table].reshape(b, p_log * ps, kv, hd)
+    k_ctx = gather_pages(k_pool, page_table)
+    v_ctx = gather_pages(v_pool, page_table)
 
     qg = q.reshape(b, c, kv, g, hd)
     s = jnp.einsum("bqkgh,bckh->bkgqc", qg, k_ctx,

@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _kernel(sc_ref,                      # (8,) scalar prefetch
             w_ref, g_ref, m_ref, v_ref,  # inputs (VMEM)
@@ -96,7 +92,7 @@ def fused_prox_update(w, g, m, v, scalars, *, rule: str = "adam",
         out_shape=[jax.ShapeDtypeStruct(w.shape, w.dtype),
                    jax.ShapeDtypeStruct(m.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(scalars, w, g, m, v)

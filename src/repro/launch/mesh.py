@@ -10,13 +10,6 @@ import jax
 import numpy as np
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """Explicit Auto axis types where supported; older jax (< AxisType) is
-    Auto-by-default and rejects the kwarg."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: (data=16, model=16) = 256 chips.
     Multi-pod:  (pod=2, data=16, model=16) = 512 chips."""
@@ -31,14 +24,15 @@ def make_production_mesh(*, multi_pod: bool = False):
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before any "
             "jax import (see launch/dryrun.py)")
     return jax.make_mesh(shape, axes, devices=devices[:n],
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests)."""
     n = data * model
     return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[:n], **_axis_type_kwargs(2))
+                         devices=jax.devices()[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def mesh_from_flag(spec: str):

@@ -40,6 +40,7 @@ from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import get_config
 from repro.core.metrics import model_size_bytes
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_from_flag
 from repro.models.model_zoo import build
 from repro.serve.step import generate
@@ -66,6 +67,7 @@ def _report_sizes(cp, dense_b: int):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--reduced", action="store_true")
@@ -86,8 +88,10 @@ def main(argv=None):
                          "their own quantization")
     ap.add_argument("--sparsity", type=float, default=0.9,
                     help="fraction of weight blocks pruned before compression")
-    ap.add_argument("--block", type=int, nargs=2, default=(8, 128),
-                    metavar=("BR", "BC"), help="BCSR block (out, in) view")
+    ap.add_argument("--block", type=int, nargs=2,
+                    default=CompressionPlan.block, metavar=("BR", "BC"),
+                    help="BCSR block (out, in) view; the compiled TPU "
+                         "kernels need multiples of 128")
     ap.add_argument("--min-block-sparsity", type=float, default=0.5,
                     help="dense fallback below this zero-block fraction")
     ap.add_argument("--ckpt-dir", default="",
@@ -109,6 +113,10 @@ def main(argv=None):
                     help="save the prefill logits (B, vocab) to this .npy "
                          "path — the CI sharded-vs-single-host parity gate "
                          "compares these to 1e-4")
+    ap.add_argument("--hlo-out", default="",
+                    help="with --logits-out: also save that prefill's "
+                         "compiled HLO text (how a --mesh run partitions "
+                         "the kernels and what it gathers)")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--top-k", type=int, default=0,
                     help="top-k truncation when sampling (0 = off)")
@@ -207,6 +215,9 @@ def main(argv=None):
             "--quantize-bits applies to the --sparse prune path only "
             "(checkpoints carry their own quantization; without --sparse "
             "nothing is compressed to quantize)")
+    if args.hlo_out and not args.logits_out:
+        raise SystemExit("--hlo-out saves the --logits-out prefill's HLO; "
+                         "pass --logits-out too")
 
     model = build(args.arch, reduced=args.reduced)
     cfg = model.cfg
@@ -268,10 +279,16 @@ def main(argv=None):
     with mesh_ctx:
         if args.logits_out:
             cache = model.init_cache(args.batch, args.prompt_len + args.gen)
-            logits, _ = jax.jit(model.prefill)(params, prompt, cache)
+            prefill = jax.jit(model.prefill).lower(params, prompt,
+                                                   cache).compile()
+            logits, _ = prefill(params, prompt, cache)
             np.save(args.logits_out,
                     np.asarray(jax.device_get(logits)).astype(np.float32))
             print(f"prefill logits -> {args.logits_out}")
+            if args.hlo_out:
+                with open(args.hlo_out, "w") as f:
+                    f.write(prefill.as_text())
+                print(f"prefill HLO -> {args.hlo_out}")
         if args.engine:
             return _run_engine(model, params, args)
         t0 = time.perf_counter()
@@ -454,6 +471,7 @@ def _run_engine(model, params, args):
     print("sample:", [int(t) for t in out["results"][0][:16]])
     if args.parity_check:
         _check_parity(model, params, args, requests, out["results"])
+    out["engine"] = engine
     return out
 
 

@@ -17,7 +17,7 @@ loads and serves from directly:
 
     PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --reduced \
         --sparse --steps 60 --debias-steps 20 --compress group_l1:0.05 \
-        --block 8 64 --ckpt-dir /tmp/spc
+        --ckpt-dir /tmp/spc
 
 ``--quantize-bits 8|4`` adds Deep Compression stage 2 on top: after debias,
 the BlockCSR block data is k-means palette-quantized (``PaletteBCSR``,
@@ -41,6 +41,7 @@ from repro.core import metrics as metrics_lib
 from repro.core.optimizers import prox_adam, prox_rmsprop, prox_sgd
 from repro.data.synthetic import TokenStreamConfig, token_batch
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_from_flag
 from repro.models import frontends
 from repro.models.model_zoo import build
@@ -62,6 +63,7 @@ def parse_compress(spec: str):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--reduced", action="store_true")
@@ -111,9 +113,10 @@ def main(argv=None):
                          "data to PaletteBCSR at this bit width (0 = off); "
                          "the checkpoint then serves from the quantized "
                          "form directly")
-    ap.add_argument("--block", type=int, nargs=2, default=(8, 64),
-                    metavar=("BR", "BC"),
-                    help="BCSR block on the (out, in) view (--sparse)")
+    ap.add_argument("--block", type=int, nargs=2,
+                    default=CompressionPlan.block, metavar=("BR", "BC"),
+                    help="BCSR block on the (out, in) view (--sparse); the "
+                         "compiled TPU kernels need multiples of 128")
     ap.add_argument("--min-block-sparsity", type=float, default=0.3,
                     help="dense fallback below this zero-block fraction")
     args = ap.parse_args(argv)

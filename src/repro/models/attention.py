@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import shard_ann
+from repro.distributed.sharding import run_replicated, shard_ann
 from repro.kernels.paged_attention import ops as paged_kops
 from repro.models.layers import apply_norm, apply_rope, init_norm, truncated_normal_init
 from repro.sparse import ops as sparse_ops
@@ -347,25 +347,27 @@ def prefill_attention(p: dict, x: Array, cache: dict, positions: Array,
 
 def init_paged_kv(cfg: ModelConfig, n_pages: int, page_size: int,
                   dtype) -> dict:
-    """Per-layer block-paged KV pool: K/V stored as (n_pages, page_size, kv,
-    hd) pages shared by every request slot. Page 0 is the engine's trash
+    """Per-layer block-paged KV pool: K/V stored as head-major (n_pages, kv,
+    page_size, hd) pages shared by every request slot — one (page_size, hd)
+    slab per (page, head) is the tile the Pallas kernel DMAs (Mosaic's
+    tiling rule), whatever the head count. Page 0 is the engine's trash
     page — never allocated to a request, so masked-out token writes can
     land there harmlessly. Slot-to-page ownership lives in the engine's
     page table, not here.
 
     kv_cache_dtype='int8': pages store int8 K/V plus one f32 scale per
-    (page, offset, head) — the same static symmetric scheme as the ring
+    (page, head, offset) — the same static symmetric scheme as the ring
     cache (``_quantize_heads``), halving page-pool HBM."""
     kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     if cfg.kv_cache_dtype == "int8":
         return {
-            "k": jnp.zeros((n_pages, page_size, kv, hd), jnp.int8),
-            "v": jnp.zeros((n_pages, page_size, kv, hd), jnp.int8),
-            "k_scale": jnp.zeros((n_pages, page_size, kv, 1), jnp.float32),
-            "v_scale": jnp.zeros((n_pages, page_size, kv, 1), jnp.float32),
+            "k": jnp.zeros((n_pages, kv, page_size, hd), jnp.int8),
+            "v": jnp.zeros((n_pages, kv, page_size, hd), jnp.int8),
+            "k_scale": jnp.zeros((n_pages, kv, page_size, 1), jnp.float32),
+            "v_scale": jnp.zeros((n_pages, kv, page_size, 1), jnp.float32),
         }
-    return {"k": jnp.zeros((n_pages, page_size, kv, hd), dtype),
-            "v": jnp.zeros((n_pages, page_size, kv, hd), dtype)}
+    return {"k": jnp.zeros((n_pages, kv, page_size, hd), dtype),
+            "v": jnp.zeros((n_pages, kv, page_size, hd), dtype)}
 
 
 def paged_attention(p: dict, x: Array, cache: dict, page_table: Array,
@@ -378,7 +380,7 @@ def paged_attention(p: dict, x: Array, cache: dict, page_table: Array,
     x: (B, C, d) — B engine slots, up to C new tokens each; slot i carries
     ``n_tokens[i]`` valid tokens at absolute positions ``positions[i, :]``
     (decode slots have 1 valid token, prefill slots a chunk, inactive slots
-    0). cache: {"k", "v"} (n_pages, page_size, kv, hd) pools; page_table:
+    0). cache: {"k", "v"} (n_pages, kv, page_size, hd) pools; page_table:
     (B, P) physical page of each slot's logical page p (covering positions
     [p*page_size, (p+1)*page_size)), 0 for unallocated entries.
 
@@ -398,7 +400,7 @@ def paged_attention(p: dict, x: Array, cache: dict, page_table: Array,
     oracle; None/'auto' picks pallas on TPU, ref elsewhere.
     """
     b, c = x.shape[0], x.shape[1]
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[2]
     q, k_new, v_new = _project_qkv(p, x, cfg, positions, sparse)
 
     valid = jnp.arange(c, dtype=jnp.int32)[None, :] < n_tokens[:, None]
@@ -406,6 +408,9 @@ def paged_attention(p: dict, x: Array, cache: dict, page_table: Array,
     phys = jnp.take_along_axis(page_table, logical, axis=1)     # (B, C)
     phys = jnp.where(valid, phys, 0)                            # trash page
     offs = positions % ps
+    # (page, :, offset) scatter: advanced indices split by the head slice
+    # put the B*C token axis first, matching the (B*C, kv, ...) updates
+    at = (phys.reshape(-1), slice(None), offs.reshape(-1))
     new_cache = {}
     if cfg.kv_cache_dtype == "int8":
         # Same static symmetric scheme as the ring cache: quantize the new
@@ -414,12 +419,10 @@ def paged_attention(p: dict, x: Array, cache: dict, page_table: Array,
         # (fusing the dequant into the page-gather kernel is future work).
         for name, new in (("k", k_new), ("v", v_new)):
             qn, sc = _quantize_heads(new)
-            new_cache[name] = cache[name].at[
-                phys.reshape(-1), offs.reshape(-1)].set(
-                    qn.reshape(b * c, *qn.shape[2:]))
-            new_cache[name + "_scale"] = cache[name + "_scale"].at[
-                phys.reshape(-1), offs.reshape(-1)].set(
-                    sc.reshape(b * c, *sc.shape[2:]))
+            new_cache[name] = cache[name].at[at].set(
+                qn.reshape(b * c, *qn.shape[2:]))
+            new_cache[name + "_scale"] = cache[name + "_scale"].at[at].set(
+                sc.reshape(b * c, *sc.shape[2:]))
         k_pool = (new_cache["k"].astype(jnp.float32)
                   * new_cache["k_scale"]).astype(x.dtype)
         v_pool = (new_cache["v"].astype(jnp.float32)
@@ -428,23 +431,22 @@ def paged_attention(p: dict, x: Array, cache: dict, page_table: Array,
         for name, new in (("k", k_new), ("v", v_new)):
             pool = cache[name]
             flat = new.reshape(b * c, *new.shape[2:]).astype(pool.dtype)
-            new_cache[name] = pool.at[phys.reshape(-1),
-                                      offs.reshape(-1)].set(flat)
+            new_cache[name] = pool.at[at].set(flat)
         k_pool, v_pool = new_cache["k"], new_cache["v"]
 
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     g = h // kv
     if sparse_ops.resolve_backend(backend or "auto") == "pallas":
-        out = paged_kops.paged_flash_attention(
-            q, k_pool, v_pool, page_table, positions,
-            window=cfg.attn_window, kv_splits=kv_splits)
+        out = run_replicated(functools.partial(
+            paged_kops.paged_flash_attention, window=cfg.attn_window,
+            kv_splits=kv_splits), q, k_pool, v_pool, page_table, positions)
         out = out.astype(x.dtype)
         y = _out_proj(p, out, x.dtype, sparse)
         return shard_ann(y, ("batch", "seq", "embed")), new_cache
 
     P = page_table.shape[1]
-    k_ctx = k_pool[page_table].reshape(b, P * ps, *k_new.shape[2:])
-    v_ctx = v_pool[page_table].reshape(b, P * ps, *v_new.shape[2:])
+    k_ctx = paged_kops.gather_pages(k_pool, page_table)
+    v_ctx = paged_kops.gather_pages(v_pool, page_table)
     k_ctx = shard_ann(k_ctx, ("batch", "cache_seq", "kv_heads", "head_dim"))
     v_ctx = shard_ann(v_ctx, ("batch", "cache_seq", "kv_heads", "head_dim"))
 

@@ -27,19 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax moved shard_map out of experimental and (separately) renamed
-# check_rep -> check_vma; support both, keying the kwarg on the actual
-# signature rather than on where shard_map lives — the promotion and the
-# rename did not happen in the same release.
-import inspect as _inspect
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                           # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-_CHECK_REP_KW = ("check_vma" if "check_vma"
-                 in _inspect.signature(_shard_map).parameters else "check_rep")
-
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.distributed.sharding import current_mesh, shard_ann
 from repro.models.layers import activation, truncated_normal_init
@@ -187,13 +174,13 @@ def _apply_moe_shard_map(p: dict, x: Array, cfg: ModelConfig,
     bspec = batch_axes if len(batch_axes) > 1 else (batch_axes[0]
                                                     if batch_axes else None)
     xspec = P(bspec if b % dp == 0 else None, None, None)
-    y, me, ce, z = _shard_map(
+    y, me, ce, z = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, P(None, None),
                   P("model", "data", None), P("model", "data", None),
                   P("model", None, "data")),
         out_specs=(xspec, P(None), P(None), P()),
-        **{_CHECK_REP_KW: False},
+        check_vma=False,
     )(x, p["router"], p["ewi"], p["ewg"], p["ewo"])
 
     aux = {"load_balance": e.n_experts * jnp.sum(me * ce),

@@ -30,12 +30,8 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
-try:                                     # jax.core.Tracer moved across
-    from jax.core import Tracer as _Tracer       # jax versions; tolerate both
-except Exception:                                # pragma: no cover
-    from jax._src.core import Tracer as _Tracer  # type: ignore
-
 import jax
+from jax.core import Tracer as _Tracer
 
 # the active profiler the kernel hooks consult — None = zero-overhead path
 _ACTIVE: Optional["Profiler"] = None
@@ -72,11 +68,16 @@ class Profiler:
         global _ACTIVE
         _ACTIVE = self
         if self.jax_trace_dir:
+            # a trace that was asked for and cannot start is an error: a run
+            # without it would look traced and hold no device timeline
             try:
                 jax.profiler.start_trace(self.jax_trace_dir)
-                self._tracing = True
-            except Exception:              # backend without profiler support
-                self._tracing = False
+            except Exception as e:
+                _ACTIVE = None
+                raise RuntimeError(
+                    f"jax.profiler.start_trace({self.jax_trace_dir!r}) "
+                    f"failed: {e}") from e
+            self._tracing = True
         return self
 
     def stop(self) -> None:

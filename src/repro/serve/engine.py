@@ -257,6 +257,17 @@ class ServeEngine:
         # src/dst: one compiled shape no matter which pages are copied)
         self._copy_page = jax.jit(copy_page, donate_argnums=(0,))
 
+    def lower_step(self, width: int):
+        """The jitted mixed step lowered for one tick of ``width`` tokens
+        per slot (``jax.stages.Lowered``) — what the served program
+        contains, e.g. which Pallas kernels (``tpu_custom_call``)."""
+        b = self.config.max_batch
+        return self._step.lower(
+            self.params, self.pools, jnp.zeros((b, width), jnp.int32),
+            jnp.asarray(self.scheduler.page_table()),
+            jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
+            self._rng)
+
     @property
     def n_ticks(self) -> int:
         """Total ticks run (registry-backed; all compiled widths)."""

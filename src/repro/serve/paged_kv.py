@@ -7,12 +7,12 @@ without reallocating (= recompiling) the whole batch. The engine instead
 gives every request slot a **slot resource pool** per layer, of which there
 are two kinds, keyed by the layer kind:
 
-* **Block-paged KV** (``attn`` layers): a pool of
-  ``(n_pages, page_size, kv_heads, head_dim)`` K and V pages shared by
+* **Block-paged KV** (``attn`` layers): a pool of head-major
+  ``(n_pages, kv_heads, page_size, head_dim)`` K and V pages shared by
   every request slot, mapped onto each request's logical context through a
   per-slot **page table** ``(capacity, max_pages)``: logical page ``p`` of
   a slot covers absolute positions ``[p*page_size, (p+1)*page_size)``.
-  Int8-KV configs store int8 pages plus per-(page, offset, head) f32
+  Int8-KV configs store int8 pages plus per-(page, head, offset) f32
   scales (``attention.init_paged_kv``).
 * **Slot-indexed recurrent state** (``rglru``/``rwkv`` layers): fixed-size
   state arrays with a leading ``capacity`` axis — slot ``i``'s state lives
@@ -80,7 +80,7 @@ def init_paged_cache(model: Model, n_pages: int, page_size: int,
 
     Mirrors ``Model.init_cache``'s tree (``{"layers": stacked, "rem": ...}``)
     with each attention layer's ring buffer replaced by a
-    ``(n_pages, page_size, kv, hd)`` page pool and each recurrent layer's
+    ``(n_pages, kv, page_size, hd)`` page pool and each recurrent layer's
     state replaced by a ``capacity``-slot state pool. One page table
     indexes every attention layer's pool identically (all layers cache the
     same positions), so the engine allocates pages once per request, not
@@ -178,7 +178,7 @@ def copy_page(pools, src, dst):
     of which pages are copied.
 
     In the stacked ``"layers"`` group the page axis is axis 1 (leaves are
-    ``(n_super, n_pages, page_size, ...)``); in ``"rem"`` it is axis 0.
+    ``(n_super, n_pages, kv, page_size, ...)``); in ``"rem"`` it is axis 0.
     """
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)

@@ -78,7 +78,10 @@ def _lead_axes(name: str, stacked: bool) -> int:
 class CompressionPlan:
     """What to compress and how.
 
-    block:        default (br, bc) BCSR tile, on the (out, in) view.
+    block:        default (br, bc) BCSR tile, on the (out, in) view. The
+                  compiled TPU kernels need both dims to be multiples of
+                  128 (``kernels.bsr_spmm.check_block_tiling``); smaller
+                  tiles run only in interpret mode or on the ref backend.
     min_sparsity: minimum fraction of all-zero blocks; below it the matrix
                   stays dense (fallback). For stacked layers the *worst*
                   slice must clear the bar (the stack compresses uniformly).
@@ -101,7 +104,7 @@ class CompressionPlan:
                   1 otherwise. Padding slots are zero blocks — output- and
                   gradient-invariant (``pad_bcsr``).
     """
-    block: tuple[int, int] = (8, 128)
+    block: tuple[int, int] = (128, 128)
     min_sparsity: float = 0.5
     min_size: int = 4096
     overrides: tuple = ()

@@ -208,21 +208,23 @@ def pad_bcsr(m: BlockCSR, n_slots: int, jmax: int, jmax_t: int) -> BlockCSR:
 def pack_uint4(codes):
     """Pack uint8 codes < 16 two-per-byte along the last axis (must be even).
 
-    Convention: byte k holds codes[2k] in the low nibble and codes[2k+1] in
-    the high nibble, so ``unpack_uint4(pack_uint4(c)) == c``.
+    Convention: with n = codes.shape[-1], byte k holds codes[k] in the low
+    nibble and codes[k + n/2] in the high nibble, so unpacking is a
+    concatenation of the two nibble planes (which Mosaic compiles; an
+    interleave is an unsupported shape cast) and
+    ``unpack_uint4(pack_uint4(c)) == c``.
     """
     assert codes.shape[-1] % 2 == 0, codes.shape
     c = jnp.asarray(codes, jnp.uint8)
-    return (c[..., 0::2] | (c[..., 1::2] << 4)).astype(jnp.uint8)
+    half = c.shape[-1] // 2
+    return (c[..., :half] | (c[..., half:] << 4)).astype(jnp.uint8)
 
 
 def unpack_uint4(packed):
-    """Inverse of ``pack_uint4``: (..., n) uint8 -> (..., 2n) uint8 codes."""
-    p = jnp.asarray(packed, jnp.uint8)
-    lo = p & 0xF
-    hi = p >> 4
-    return jnp.stack([lo, hi], axis=-1).reshape(*p.shape[:-1],
-                                                p.shape[-1] * 2)
+    """Inverse of ``pack_uint4``: (..., n) -> (..., 2n) codes, in the
+    integer dtype of ``packed`` (uint8 storage, or int32 inside a kernel)."""
+    p = jnp.asarray(packed)
+    return jnp.concatenate([p & 0xF, p >> 4], axis=-1)
 
 
 def dequantize_codes(codes, palette, bits: int):

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.distributed.sharding import run_replicated
 from repro.kernels.bsr_sddmm import ops as sddmm_kops
 from repro.kernels.bsr_spmm import ops as kops
 from repro.sparse.formats import BlockCSR, PaletteBCSR
@@ -58,13 +59,13 @@ def resolve_backend(backend: str) -> str:
 
 def _fwd_product(x, w: BlockCSR, backend: str):
     if backend == "pallas":
-        return kops.spmm(x, w)
+        return run_replicated(kops.spmm, x, w)
     return kops.spmm_fwd_ref(x, w).astype(x.dtype)
 
 
 def _bwd_dx_product(dy, w: BlockCSR, backend: str):
     if backend == "pallas":
-        return kops.spmm_t(dy, w)
+        return run_replicated(kops.spmm_t, dy, w)
     return kops.spmm_bwd_ref(dy, w).astype(dy.dtype)
 
 
@@ -90,7 +91,8 @@ def _sparse_matmul_bwd(backend, res, dy):
     # dw via SDDMM at the resident slots only: (n_slots, br, bc) aligned
     # with w.data. The kernel runs in interpret mode off-TPU; there is no
     # dense (out, in) intermediate on any backend.
-    dw_data = sddmm_kops.bsr_weight_grad(x, dy, w).astype(w.data.dtype)
+    dw_data = run_replicated(sddmm_kops.bsr_weight_grad, x, dy,
+                             w).astype(w.data.dtype)
     # zero cotangents for every side array (float0 for int indices), real
     # gradient only at the block data — tree.map keeps the field list in
     # one place (the dataclass registration)
@@ -103,7 +105,7 @@ _sparse_matmul.defvjp(_sparse_matmul_fwd, _sparse_matmul_bwd)
 
 def _palette_fwd_product(x, w: PaletteBCSR, backend: str):
     if backend == "pallas":
-        return kops.spmm_palette(x, w)
+        return run_replicated(kops.spmm_palette, x, w)
     return kops.spmm_palette_fwd_ref(x, w).astype(x.dtype)
 
 

@@ -24,8 +24,8 @@ from repro.models.model_zoo import build
 from repro.train.state import TrainState
 from repro.train.step import make_train_step
 
-from repro.launch.mesh import _axis_type_kwargs
-mesh = jax.make_mesh((2, 4), ("data", "model"), **_axis_type_kwargs(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 model = build("qwen3-0.6b", reduced=True, remat=False)
 cfg = model.cfg
 opt = prox_adam(1e-3, lam=0.5)
@@ -92,8 +92,8 @@ from repro.configs import get_config
 from repro.distributed import sharding as shd
 from repro.models import moe as moe_lib
 
-from repro.launch.mesh import _axis_type_kwargs
-mesh = jax.make_mesh((2, 4), ("data", "model"), **_axis_type_kwargs(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_config("olmoe-1b-7b").reduced()
 cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
     cfg.moe, capacity_factor=float(cfg.moe.n_experts)))  # no-drop: exact

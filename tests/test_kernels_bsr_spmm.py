@@ -104,3 +104,24 @@ def test_ragged_rows_padded_gather():
     x = jnp.asarray(np.eye(96, dtype=np.float32))
     y = ops.spmm(x, m, bm=8)
     np.testing.assert_allclose(np.asarray(y), w.T, atol=1e-5)
+
+
+@pytest.mark.parametrize("kernel", ["spmm", "spmm_t", "sddmm", "palette"])
+def test_compiled_path_rejects_untileable_block(kernel):
+    """A block the compiled (Mosaic) kernel cannot tile raises at trace
+    time and names the rule — it never falls back to interpret mode."""
+    from repro.kernels.bsr_sddmm import ops as sddmm_ops
+    from repro.sparse.compress import quantize_bcsr
+
+    rng = np.random.default_rng(5)
+    m = dense_to_bcsr(_block_sparse(rng, 64, 128, (8, 128), 0.5), (8, 128))
+    x = jnp.ones((16, 128), jnp.float32)
+    dy = jnp.ones((16, 64), jnp.float32)
+    calls = {"spmm": lambda: ops.spmm(x, m, interpret=False),
+             "spmm_t": lambda: ops.spmm_t(dy, m, interpret=False),
+             "sddmm": lambda: sddmm_ops.bsr_weight_grad(x, dy, m,
+                                                        interpret=False),
+             "palette": lambda: ops.spmm_palette(
+                 x, quantize_bcsr(m, 8, iters=2), interpret=False)}
+    with pytest.raises(ValueError, match=r"multiples of \(8, 128\)"):
+        calls[kernel]()

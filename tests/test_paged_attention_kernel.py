@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -39,8 +40,8 @@ def _scenario(rng, *, b=3, c=8, kv=2, g=3, hd=16, ps=4, p_log=6,
     h = kv * g
     n_pages = 1 + b * p_log
     q = jnp.asarray(rng.normal(size=(b, c, h, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, ps, kv, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, ps, kv, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(n_pages, kv, ps, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(n_pages, kv, ps, hd)), jnp.float32)
     table = jnp.asarray(
         1 + np.arange(b * p_log, dtype=np.int32).reshape(b, p_log))
     starts = np.asarray(starts, np.int32)
@@ -111,9 +112,9 @@ def _tiny_cfg(**kw):
 
 
 def _subjaxprs_of(p):
-    if isinstance(p, jax.core.ClosedJaxpr):
+    if isinstance(p, jax.extend.core.ClosedJaxpr):
         yield p.jaxpr
-    elif isinstance(p, jax.core.Jaxpr):
+    elif isinstance(p, jax.extend.core.Jaxpr):
         yield p
     elif isinstance(p, (list, tuple)):
         for q in p:
@@ -138,8 +139,8 @@ def test_pallas_path_never_materializes_gathered_context():
     b, c, ps, p_log = 2, 4, 4, 5
     p = attention.init_attention(jax.random.PRNGKey(0), cfg)
     x = jnp.zeros((b, c, cfg.d_model), jnp.float32)
-    cache = {"k": jnp.zeros((1 + b * p_log, ps, 2, 8), jnp.float32),
-             "v": jnp.zeros((1 + b * p_log, ps, 2, 8), jnp.float32)}
+    cache = {"k": jnp.zeros((1 + b * p_log, 2, ps, 8), jnp.float32),
+             "v": jnp.zeros((1 + b * p_log, 2, ps, 8), jnp.float32)}
     table = jnp.zeros((b, p_log), jnp.int32)
     positions = jnp.zeros((b, c), jnp.int32)
     n_tokens = jnp.zeros((b,), jnp.int32)
@@ -162,9 +163,9 @@ def test_attention_backends_agree(window):
     b, c, ps, p_log = 2, 4, 4, 5
     p = attention.init_attention(jax.random.PRNGKey(1), cfg)
     x = jnp.asarray(rng.normal(size=(b, c, cfg.d_model)) * 0.1, jnp.float32)
-    cache = {"k": jnp.asarray(rng.normal(size=(1 + b * p_log, ps, 2, 8)),
+    cache = {"k": jnp.asarray(rng.normal(size=(1 + b * p_log, 2, ps, 8)),
                               jnp.float32),
-             "v": jnp.asarray(rng.normal(size=(1 + b * p_log, ps, 2, 8)),
+             "v": jnp.asarray(rng.normal(size=(1 + b * p_log, 2, ps, 8)),
                               jnp.float32)}
     table = jnp.asarray(
         1 + np.arange(b * p_log, dtype=np.int32).reshape(b, p_log))

@@ -253,6 +253,40 @@ def test_palette_checkpoint_roundtrip(tmp_path, quantized_setup):
     assert m0.bits == 8 and m0.codes.dtype == jnp.uint8
 
 
+def test_palette4_checkpoint_restores_interleaved_nibbles(tmp_path):
+    """A 4-bit checkpoint written with the older interleaved nibble packing
+    (no "nibbles" marker in its manifest entry) restores to the same codes
+    as one written with the split packing; an unknown marker is refused."""
+    import json
+    import os
+
+    q = quantize_bcsr(dense_to_bcsr(_block_sparse(shape=(64, 256)),
+                                    block=(8, 64)), 4)
+    ckpt = Checkpointer(str(tmp_path))
+    path = ckpt.save(1, {"w": q})
+    assert ckpt.manifest(1)["leaves"][0]["nibbles"] == "split"
+
+    codes = np.asarray(unpack_uint4(q.codes))
+    arrays = dict(np.load(os.path.join(path, "arrays.npz")))
+    arrays["w__codes"] = (codes[..., 0::2] | (codes[..., 1::2] << 4)
+                          ).astype(np.uint8)
+    np.savez(os.path.join(path, "arrays.npz"), **arrays)
+    manifest = ckpt.manifest(1)
+    del manifest["leaves"][0]["nibbles"]
+    with open(os.path.join(path, "manifest.json"), "w") as f:
+        json.dump(manifest, f)
+    back = ckpt.restore(1, like={"w": q})["w"]
+    np.testing.assert_array_equal(np.asarray(back.codes), np.asarray(q.codes))
+    np.testing.assert_array_equal(np.asarray(back.to_dense()),
+                                  np.asarray(q.to_dense()))
+
+    manifest["leaves"][0]["nibbles"] = "zigzag"
+    with open(os.path.join(path, "manifest.json"), "w") as f:
+        json.dump(manifest, f)
+    with pytest.raises(ValueError, match="nibble packing"):
+        ckpt.restore(1, like={"w": q})
+
+
 def test_quantized_is_serving_only(quantized_setup):
     _, _, qcp = quantized_setup
     with pytest.raises(TypeError, match="serving-only"):

@@ -122,6 +122,9 @@ def test_request_roundtrip_seeded(idx):
     assert Request.from_json(r.to_json()) == r
 
 
+# hypothesis needs float32-exact bounds with width=32; 0.01 is not one
+TOP_P_MIN = float(np.float32(0.01))
+
 if HAVE_HYPOTHESIS:
     @hypothesis.given(
         st.lists(st.integers(0, 10_000), min_size=1, max_size=64),
@@ -132,7 +135,7 @@ if HAVE_HYPOTHESIS:
             SamplingParams,
             temperature=st.floats(0, 4, allow_nan=False, width=32),
             top_k=st.integers(0, 100),
-            top_p=st.floats(0.01, 1.0, allow_nan=False, width=32)))
+            top_p=st.floats(TOP_P_MIN, 1.0, allow_nan=False, width=32)))
     @hypothesis.settings(max_examples=60, deadline=None)
     def test_request_roundtrip_property(prompt, gen, eos, priority,
                                         sampling):
@@ -146,7 +149,7 @@ if HAVE_HYPOTHESIS:
         SamplingParams,
         temperature=st.floats(0, 4, allow_nan=False, width=32),
         top_k=st.integers(0, 100),
-        top_p=st.floats(0.01, 1.0, allow_nan=False, width=32)))
+        top_p=st.floats(TOP_P_MIN, 1.0, allow_nan=False, width=32)))
     @hypothesis.settings(max_examples=60, deadline=None)
     def test_sampling_roundtrip_property(sp):
         assert SamplingParams.from_json(sp.to_json()) == sp

@@ -17,6 +17,7 @@ Covers the compressed-training tentpole:
 import dataclasses
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -74,8 +75,13 @@ def test_plan_prox_matches_out_in_group_l1(path, shape):
     view = _as_out_in(slash, np.asarray(z))
     want_view = prox_group_l1_blocks(jnp.asarray(view), tau, block=(8, 32))
     got_view = _as_out_in(slash, np.asarray(got))
+    # The two sides sum each block norm in a different order (stored layout
+    # vs (out, in) view), so the norms can differ by a few float32 ulps. The
+    # scale 1 - tau/norm carries that error to every element of the block,
+    # multiplied by |z|: allow a few ulps of the largest |z|.
+    atol = 4 * np.finfo(np.float32).eps * float(np.abs(np.asarray(z)).max())
     np.testing.assert_allclose(got_view, np.asarray(want_view),
-                               atol=1e-6, rtol=1e-6)
+                               atol=atol, rtol=1e-6)
     # must produce whole zero blocks on that grid
     m = dense_to_bcsr(np.asarray(got_view), (8, 32))
     grid = int(np.prod(m.block_grid))
@@ -217,9 +223,9 @@ def test_dw_stacked_layers_through_scan():
 
 
 def _subjaxprs_of(p):
-    if isinstance(p, jax.core.ClosedJaxpr):
+    if isinstance(p, jax.extend.core.ClosedJaxpr):
         yield p.jaxpr
-    elif isinstance(p, jax.core.Jaxpr):
+    elif isinstance(p, jax.extend.core.Jaxpr):
         yield p
     elif isinstance(p, (list, tuple)):
         for q in p:
