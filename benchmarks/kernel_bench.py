@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import use_interpret
-from repro.obs.profile import Profiler
 from repro.kernels.bsr_sddmm import ops as sddmm_ops
 from repro.kernels.bsr_spmm import ops as spmm_ops
 from repro.kernels.bsr_spmm import ref as spmm_ref
@@ -175,23 +174,6 @@ def _sddmm_row(name, rng, sparsity, iters):
 
 
 def run(iters: int = 3):
-    # the obs kernel_call hooks see every public kernel entry the bench
-    # exercises — the profiler summary rides along as its own BENCH row
-    with Profiler() as prof:
-        rows = _run_rows(iters)
-    summary = prof.summary()
-    if summary:
-        total_ms = sum(r["total_ms"] for r in summary.values())
-        derived = ",".join(
-            f"{name.replace('/', '_')}_ms={r['total_ms']:.1f}"
-            for name, r in sorted(summary.items()))
-        rows.append({"name": "kernel/profile_hooks",
-                     "us_per_call": total_ms * 1e3,
-                     "derived": derived, "profile": summary})
-    return rows
-
-
-def _run_rows(iters: int):
     rows = []
     rng = np.random.default_rng(0)
 

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from repro.kernels.bsr_sddmm.bsr_sddmm import sddmm_block_grad
 from repro.kernels.bsr_sddmm import ref as ref_lib
 from repro.kernels import use_interpret
-from repro.obs.profile import kernel_call
 from repro.sparse.formats import BlockCSR, PaletteBCSR
 
 
@@ -82,8 +81,7 @@ def bsr_weight_grad(x, dy, w: BlockCSR, *, bm: int = 128,
 
     Returns (n_slots, br, bc) f32 gradient blocks for w.data."""
     _reject_palette(w)
-    return kernel_call("bsr_sddmm/bsr_weight_grad", _bsr_weight_grad, x, dy,
-                       w, bm=bm, interpret=interpret)
+    return _bsr_weight_grad(x, dy, w, bm=bm, interpret=interpret)
 
 
 def bsr_weight_grad_ref(x, dy, w: BlockCSR):

@@ -17,7 +17,6 @@ from repro.kernels.bsr_spmm.bsr_spmm import (gather_block_matmul,
                                              gather_block_matmul_palette)
 from repro.kernels.bsr_spmm import ref as ref_lib
 from repro.kernels import use_interpret
-from repro.obs.profile import kernel_call
 from repro.sparse.formats import BlockCSR, PaletteBCSR
 
 
@@ -45,8 +44,7 @@ def _spmm(x, w: BlockCSR, *, bm: int = 128, interpret: bool | None = None):
 
 def spmm(x, w: BlockCSR, *, bm: int = 128, interpret: bool | None = None):
     """Y (M, N) = X (M, K) @ W' for W (N, K) BlockCSR."""
-    return kernel_call("bsr_spmm/spmm", _spmm, x, w, bm=bm,
-                       interpret=interpret)
+    return _spmm(x, w, bm=bm, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
@@ -67,8 +65,7 @@ def _spmm_t(dy, w: BlockCSR, *, bm: int = 128, interpret: bool | None = None):
 
 def spmm_t(dy, w: BlockCSR, *, bm: int = 128, interpret: bool | None = None):
     """dX (M, K) = dY (M, N) @ W for W (N, K) BlockCSR (backward)."""
-    return kernel_call("bsr_spmm/spmm_t", _spmm_t, dy, w, bm=bm,
-                       interpret=interpret)
+    return _spmm_t(dy, w, bm=bm, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
@@ -92,8 +89,7 @@ def spmm_palette(x, w: PaletteBCSR, *, bm: int = 128,
     """Y (M, N) = X (M, K) @ W' for W (N, K) PaletteBCSR — the quantized
     serving forward. Dequantization (palette lookup, nibble unpack at 4-bit)
     is fused into the gather-block-matmul kernel."""
-    return kernel_call("bsr_spmm/spmm_palette", _spmm_palette, x, w, bm=bm,
-                       interpret=interpret)
+    return _spmm_palette(x, w, bm=bm, interpret=interpret)
 
 
 @jax.custom_vjp

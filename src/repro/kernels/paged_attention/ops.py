@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from repro.kernels import use_interpret
 from repro.kernels.paged_attention import ref as ref_lib
 from repro.kernels.paged_attention.paged_attention import paged_flash_fwd
-from repro.obs.profile import kernel_call
 
 
 @functools.partial(jax.jit,
@@ -67,10 +66,9 @@ def paged_flash_attention(q, k_pool, v_pool, page_table, positions, *,
     Returns (B, C, H, hd) f32 attention output; invalid query rows carry
     finite garbage exactly like the ref path.
     """
-    return kernel_call("paged_attention/paged_flash_attention",
-                       _paged_flash_attention, q, k_pool, v_pool, page_table,
-                       positions, window=window, kv_splits=kv_splits,
-                       interpret=interpret)
+    return _paged_flash_attention(q, k_pool, v_pool, page_table, positions,
+                                  window=window, kv_splits=kv_splits,
+                                  interpret=interpret)
 
 
 paged_attention_ref = ref_lib.paged_attention_ref

@@ -16,7 +16,6 @@ from repro.core.prox import default_regularized_predicate
 from repro.kernels.prox_adam.prox_adam import fused_prox_update
 from repro.kernels import use_interpret
 from repro.kernels.prox_adam import ref as ref_lib
-from repro.obs.profile import kernel_call
 _LANES = 128
 
 
@@ -55,9 +54,9 @@ def _fused_update_leaf(w, g, m, v, scalars, *, rule="adam", apply_prox=True,
 
 def fused_update_leaf(w, g, m, v, scalars, *, rule="adam", apply_prox=True,
                       bm=256, interpret=None):
-    return kernel_call("prox_adam/fused_update_leaf", _fused_update_leaf,
-                       w, g, m, v, scalars, rule=rule, apply_prox=apply_prox,
-                       bm=bm, interpret=interpret)
+    return _fused_update_leaf(w, g, m, v, scalars, rule=rule,
+                              apply_prox=apply_prox, bm=bm,
+                              interpret=interpret)
 
 
 def make_scalars(lr, lam, b1, b2, eps, t):

@@ -190,15 +190,14 @@ def main(argv=None):
     ap.add_argument("--trace-out", default="",
                     help="with --engine: write a Chrome trace-event / "
                          "Perfetto JSON of the run (per-request lifecycle "
-                         "spans + per-tick engine spans) — load it at "
-                         "https://ui.perfetto.dev")
-    ap.add_argument("--profile", nargs="?", const="", default=None,
-                    metavar="JAX_TRACE_DIR",
-                    help="with --engine: time the jitted tick and every "
-                         "Pallas kernel entry point "
-                         "(block_until_ready-bracketed wall clock) and "
-                         "print the summary; pass a directory to also "
-                         "capture a jax.profiler trace there")
+                         "spans + the engine's tick-phase spans) — load it "
+                         "at https://ui.perfetto.dev")
+    ap.add_argument("--profile", default="", metavar="DIR",
+                    help="with --engine: record a jax.profiler trace of the "
+                         "run into DIR: device ops named by the model's "
+                         "scopes, and the engine's tick-phase spans on the "
+                         "same clock (open with TensorBoard/XProf or "
+                         "jax.profiler.ProfileData)")
     ap.add_argument("--route", default="prefix",
                     choices=["prefix", "least-loaded", "round-robin"],
                     help="router dispatch policy: 'prefix' = "
@@ -377,19 +376,21 @@ def _print_slo_classes(s):
 
 
 def _telemetry(args):
-    """--trace-out / --profile flags to (tracer, profiler) — None when the
-    flag is off (the engine then uses its zero-overhead null paths)."""
-    from repro.obs import Profiler, Tracer
+    """--trace-out / --profile flags to (tracer, profiling context): the
+    tracer is None when --trace-out is off (the engine's spans are then
+    profiler annotations only); the context brackets the run with
+    ``jax.profiler.trace(DIR)`` under --profile DIR."""
+    from repro.obs import Tracer
     tracer = Tracer() if args.trace_out else None
-    profiler = (Profiler(jax_trace_dir=args.profile or None)
-                if args.profile is not None else None)
-    return tracer, profiler
+    profiling = (jax.profiler.trace(args.profile) if args.profile
+                 else contextlib.nullcontext())
+    return tracer, profiling
 
 
-def _save_telemetry(args, save_prom, save_json, tracer, profiler):
-    """Write --metrics-out / --trace-out artifacts and print the profile
-    summary. ``save_prom(path)`` / ``save_json(path)`` are the caller's
-    exporters (engine registry, or the router's merged fleet exposition)."""
+def _save_telemetry(args, save_prom, save_json, tracer):
+    """Write --metrics-out / --trace-out artifacts. ``save_prom(path)`` /
+    ``save_json(path)`` are the caller's exporters (engine registry, or the
+    router's merged fleet exposition)."""
     if args.metrics_out:
         if args.metrics_out.endswith(".json"):
             save_json(args.metrics_out)
@@ -399,8 +400,8 @@ def _save_telemetry(args, save_prom, save_json, tracer, profiler):
     if args.trace_out and tracer is not None:
         tracer.save(args.trace_out)
         print(f"trace -> {args.trace_out} ({len(tracer.events)} events)")
-    if profiler is not None:
-        print(profiler.format_summary())
+    if args.profile:
+        print(f"profile -> {args.profile}")
 
 
 def _check_parity(model, params, args, requests, results):
@@ -434,10 +435,9 @@ def _run_engine(model, params, args):
     try:
         if args.replicas > 1:
             return _run_router(model, params, args, config, requests)
-        tracer, profiler = _telemetry(args)
+        tracer, profiling = _telemetry(args)
         engine = ServeEngine(model, params, config,
-                             rng=jax.random.PRNGKey(1),
-                             tracer=tracer, profiler=profiler)
+                             rng=jax.random.PRNGKey(1), tracer=tracer)
     except NotImplementedError as e:
         raise SystemExit(f"--engine: {e}")
     pb = engine.pool_bytes
@@ -446,8 +446,7 @@ def _run_engine(model, params, args):
           f"({engine.config.max_batch} slots)")
     from repro.serve.api import ApiValidationError
     try:
-        with (profiler if profiler is not None
-              else contextlib.nullcontext()):
+        with profiling:
             out = engine.run(requests)
     except ApiValidationError as e:
         raise SystemExit(f"--engine: {e}")
@@ -467,7 +466,7 @@ def _run_engine(model, params, args):
         print(f"  prefix cache: hit rate {s['prefix_hit_rate']:.1%} "
               f"({s['n_cached_tokens']} prompt tokens served from cache)")
     _save_telemetry(args, engine.metrics.save_prometheus,
-                    engine.metrics.save_json, tracer, profiler)
+                    engine.metrics.save_json, tracer)
     print("sample:", [int(t) for t in out["results"][0][:16]])
     if args.parity_check:
         _check_parity(model, params, args, requests, out["results"])
@@ -481,14 +480,13 @@ def _run_router(model, params, args, config, requests):
     from repro.serve.engine import ServeEngine
     from repro.serve.router import Router
 
-    tracer, profiler = _telemetry(args)
+    tracer, profiling = _telemetry(args)
     # one tracer across the fleet: router rids are globally unique, so
     # every request still gets exactly one track
-    engines = [ServeEngine(model, params, config, tracer=tracer,
-                           profiler=profiler)
+    engines = [ServeEngine(model, params, config, tracer=tracer)
                for _ in range(args.replicas)]
     router = Router(engines, policy=args.route)
-    with (profiler if profiler is not None else contextlib.nullcontext()):
+    with profiling:
         out = router.serve(requests)
     s = out["stats"]
     print(f"router[{args.replicas}x {args.route}]: {s['n_requests']} "
@@ -521,7 +519,7 @@ def _run_router(model, params, args, config, requests):
         with open(path, "w") as f:
             f.write(router.to_prometheus())
 
-    _save_telemetry(args, save_prom, save_json, tracer, profiler)
+    _save_telemetry(args, save_prom, save_json, tracer)
     print("sample:", [int(t) for t in out["results"][0][:16]])
     if args.parity_check:
         _check_parity(model, params, args, requests, out["results"])

@@ -1,17 +1,5 @@
-"""Profiling hooks: kernel/tick wall timers + the training telemetry stream.
+"""The training telemetry stream and the sparsity measurements it reports.
 
-Three tools, all off by default and free when off:
-
-* **``Profiler``** — ``block_until_ready``-bracketed wall timers. The
-  engine wraps its jitted mixed step (``engine/tick_step``) and every
-  Pallas kernel entry point routes through ``kernel_call(name, fn, ...)``:
-  when a profiler is active and the call is *eager* (concrete arrays), the
-  call is timed end-to-end including device sync; when the call happens
-  inside a ``jit`` trace (arguments are tracers — wall time there is
-  meaningless), only a traced-invocation count is recorded. When no
-  profiler is active the hook is one module-global load and a ``None``
-  check. ``jax_trace_dir`` additionally brackets the run with
-  ``jax.profiler.start_trace``/``stop_trace`` for a full XLA timeline.
 * **``TrainTelemetry``** — a per-step JSONL stream for the training loop:
   loss / grad-norm metrics, the group-l1 penalty, live per-layer block
   sparsity on the serving BCSR grid, and debias progress — the paper's
@@ -25,124 +13,8 @@ Three tools, all off by default and free when off:
 from __future__ import annotations
 
 import json
-import time
-from typing import Any, Callable, Optional
 
 import numpy as np
-
-import jax
-from jax.core import Tracer as _Tracer
-
-# the active profiler the kernel hooks consult — None = zero-overhead path
-_ACTIVE: Optional["Profiler"] = None
-
-
-def active() -> Optional["Profiler"]:
-    return _ACTIVE
-
-
-def kernel_call(name: str, fn: Callable, *args, **kwargs):
-    """The kernel entry hook: ``ops.py`` wrappers route their jitted
-    callable through this. Disabled cost: one global load + None check."""
-    p = _ACTIVE
-    if p is None:
-        return fn(*args, **kwargs)
-    return p.call(name, fn, *args, **kwargs)
-
-
-class Profiler:
-    """Wall-clock profiler for jitted entry points.
-
-    Use as a context manager (``with Profiler() as p: ...; p.summary()``)
-    or via explicit ``start()``/``stop()``. Only one profiler is active at
-    a time (the kernel hooks consult a module global)."""
-
-    def __init__(self, jax_trace_dir: Optional[str] = None):
-        self.records: dict[str, dict] = {}
-        self.jax_trace_dir = jax_trace_dir
-        self._tracing = False
-
-    # -- lifecycle ----------------------------------------------------------
-
-    def start(self) -> "Profiler":
-        global _ACTIVE
-        _ACTIVE = self
-        if self.jax_trace_dir:
-            # a trace that was asked for and cannot start is an error: a run
-            # without it would look traced and hold no device timeline
-            try:
-                jax.profiler.start_trace(self.jax_trace_dir)
-            except Exception as e:
-                _ACTIVE = None
-                raise RuntimeError(
-                    f"jax.profiler.start_trace({self.jax_trace_dir!r}) "
-                    f"failed: {e}") from e
-            self._tracing = True
-        return self
-
-    def stop(self) -> None:
-        global _ACTIVE
-        if _ACTIVE is self:
-            _ACTIVE = None
-        if self._tracing:
-            try:
-                jax.profiler.stop_trace()
-            finally:
-                self._tracing = False
-
-    def __enter__(self) -> "Profiler":
-        return self.start()
-
-    def __exit__(self, *a) -> bool:
-        self.stop()
-        return False
-
-    # -- measurement --------------------------------------------------------
-
-    def _rec(self, name: str) -> dict:
-        r = self.records.get(name)
-        if r is None:
-            r = self.records[name] = {"n_calls": 0, "total_ms": 0.0,
-                                      "max_ms": 0.0, "n_traced": 0}
-        return r
-
-    def call(self, name: str, fn: Callable, *args, **kwargs):
-        """Run ``fn(*args, **kwargs)``; eager calls are timed with a
-        ``block_until_ready`` bracket, traced calls (inside jit) are only
-        counted — a wall clock inside a trace measures tracing, not
-        compute."""
-        if any(isinstance(x, _Tracer)
-               for x in jax.tree_util.tree_leaves((args, kwargs))):
-            self._rec(name)["n_traced"] += 1
-            return fn(*args, **kwargs)
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        jax.block_until_ready(out)
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        r = self._rec(name)
-        r["n_calls"] += 1
-        r["total_ms"] += dt_ms
-        if dt_ms > r["max_ms"]:
-            r["max_ms"] = dt_ms
-        return out
-
-    def summary(self) -> dict:
-        """``{name: {n_calls, total_ms, mean_ms, max_ms, n_traced}}``."""
-        out = {}
-        for name, r in self.records.items():
-            out[name] = dict(r, mean_ms=(r["total_ms"] / r["n_calls"]
-                                         if r["n_calls"] else 0.0))
-        return out
-
-    def format_summary(self) -> str:
-        lines = ["profile (wall, block_until_ready-bracketed):"]
-        for name, r in sorted(self.summary().items(),
-                              key=lambda kv: -kv[1]["total_ms"]):
-            lines.append(
-                f"  {name:<28} {r['n_calls']:>6} calls "
-                f"{r['total_ms']:>9.1f} ms total {r['mean_ms']:>8.3f} ms/call"
-                + (f" ({r['n_traced']} traced)" if r["n_traced"] else ""))
-        return "\n".join(lines)
 
 
 # ---------------------------------------------------------------------------

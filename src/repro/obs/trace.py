@@ -18,9 +18,14 @@ Model:
   never an overlapping duplicate.
 * **Instant ("i") markers** for the point events: ``submit``,
   ``first_token``, ``preempt``, ``done``.
-* **Per-tick engine spans** on tid 0: each ``tick`` span nests a
-  ``schedule`` (host-side planning) and ``step`` (jitted mixed step) child
-  — Perfetto nests same-track spans by containment.
+* **Engine tick phases** on tid 0, from ``span()``: ``engine.tick``
+  (args: tick number, compiled ``width``, scheduled ``tokens``) nests
+  ``engine.schedule``, ``engine.prepare``, ``engine.dispatch``,
+  ``engine.sync`` and ``engine.bookkeep`` — Perfetto nests same-track
+  spans by containment. The same ``span()`` call also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name and args, so a
+  ``jax.profiler`` trace holds the phases on the device trace's clock,
+  with or without a ``Tracer`` attached.
 
 Timestamps are ``time.perf_counter`` microseconds relative to tracer
 construction; the engine stamps scheduler events with the same clock, so
@@ -28,15 +33,16 @@ trace span durations reconcile with the stats dict's ttft/latency numbers
 (tested to within a tick).
 
 ``NullTracer`` (shared ``NULL_TRACER``) is the disabled path: every hook
-is an empty method and ``span()`` hands back one reusable no-op context
-manager — tracing off costs a method call per site, nothing more.
+is an empty method, and ``span()`` opens only the profiler annotation (a
+TraceMe: about a microsecond when no profiler session is active).
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 ENGINE_TID = 0
 
@@ -88,23 +94,10 @@ class Tracer:
             ev["args"] = args
         self.events.append(ev)
 
-    @contextlib.contextmanager
-    def span(self, name: str, tid: int = ENGINE_TID, cat: str = "engine",
-             **args):
-        """Engine-side timed span (tick / schedule / step)."""
-        t0 = self.now_us()
-        mutable = dict(args)
-        try:
-            yield mutable                    # caller may add result args
-        finally:
-            self._complete(name, cat, tid, t0, self.now_us() - t0, mutable)
-
-    def complete_span(self, name: str, t0_us: int, tid: int = ENGINE_TID,
-                      cat: str = "engine", **args) -> None:
-        """Close a span opened by hand at ``t0_us = tracer.now_us()`` —
-        for spans whose begin/end straddle an early-return (the engine's
-        ``tick`` span, which is only emitted for non-idle ticks)."""
-        self._complete(name, cat, tid, t0_us, self.now_us() - t0_us, args)
+    def span(self, name: str, **args) -> "Span":
+        """Engine-side timed span: a profiler annotation plus this
+        tracer's Chrome event on the engine track."""
+        return Span(name, args, self)
 
     # -- request lifecycle --------------------------------------------------
 
@@ -185,15 +178,35 @@ class Tracer:
             f.write("\n")
 
 
-class _NullCtx:
-    def __enter__(self):
-        return {}
+class Span:
+    """One engine span (``Tracer.span`` / ``NullTracer.span``): always a
+    ``jax.profiler.TraceAnnotation``; with a ``Tracer``, also a complete
+    ("X") event on the engine track. ``set(**args)`` adds args known only
+    after the span opened (the tick's width once it is scheduled)."""
 
-    def __exit__(self, *a):
+    __slots__ = ("name", "args", "tracer", "_annotation", "_t0")
+
+    def __init__(self, name: str, args: dict, tracer=None):
+        self.name, self.args, self.tracer = name, args, tracer
+
+    def __enter__(self) -> "Span":
+        self._annotation = TraceAnnotation(self.name, **self.args)
+        self._annotation.__enter__()
+        if self.tracer is not None:
+            self._t0 = self.tracer.now_us()
+        return self
+
+    def set(self, **args) -> None:
+        self._annotation.set_metadata(**args)
+        self.args.update(args)
+
+    def __exit__(self, *exc) -> bool:
+        if self.tracer is not None:
+            t0 = self._t0
+            self.tracer._complete(self.name, "engine", ENGINE_TID, t0,
+                                  self.tracer.now_us() - t0, self.args)
+        self._annotation.__exit__(*exc)
         return False
-
-
-_NULL_CTX = _NullCtx()
 
 
 class NullTracer:
@@ -209,11 +222,8 @@ class NullTracer:
     def instant(self, *a, **k):
         pass
 
-    def span(self, *a, **k):
-        return _NULL_CTX
-
-    def complete_span(self, *a, **k):
-        pass
+    def span(self, name: str, **args) -> Span:
+        return Span(name, args)
 
     def phase(self, *a, **k):
         pass

@@ -164,7 +164,7 @@ class ServeEngine:
 
     def __init__(self, model: Model, params, config: EngineConfig,
                  sampler: Optional[Callable] = None, rng=None, *,
-                 metrics=None, tracer=None, profiler=None):
+                 metrics=None, tracer=None):
         if model.paged_step is None:
             bad = unsupported_kinds(model)
             raise NotImplementedError(
@@ -184,10 +184,10 @@ class ServeEngine:
         # and the Prometheus exposition read the same numbers. Default is a
         # live (cheap) registry; pass obs.NULL_REGISTRY to strip telemetry
         # entirely (stats counters then read 0), obs.Tracer for a lifecycle
-        # trace, obs.Profiler to time the jitted step.
+        # trace. The tick's phase spans reach a jax.profiler trace either
+        # way (obs.trace.Span).
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.tracer = tracer if tracer is not None else NULL_TRACER
-        self.profiler = profiler
         self.pools = init_paged_cache(model, config.total_pages,
                                       config.page_size,
                                       capacity=config.max_batch)
@@ -235,6 +235,7 @@ class ServeEngine:
         sampler = sampler or make_sampler(config.sampling)
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
         self._next_rid = 0
+        self._tick_no = 0                    # step() calls, the span's arg
         self.tick_widths: set[int] = set()   # distinct compiled step shapes
 
         def _step(params, pools, tokens, page_table, start_pos, n_tokens,
@@ -243,7 +244,9 @@ class ServeEngine:
                                              page_table, start_pos, n_tokens,
                                              backend=config.attn_backend,
                                              kv_splits=config.kv_splits)
-            return sampler(logits, rng), logits, pools
+            with jax.named_scope("sample"):
+                sampled = sampler(logits, rng)
+            return sampled, logits, pools
 
         # donate the pools: the KV pages update in place instead of
         # copying the whole pool every tick (no-op on backends without
@@ -344,60 +347,72 @@ class ServeEngine:
     # -- the step loop ------------------------------------------------------
 
     def step(self) -> list[dict]:
-        """Run one tick; returns the requests that finished during it."""
-        tracer = self.tracer
-        tick_t0 = tracer.now_us()           # tick span opens at schedule
-        plan = self.scheduler.next_tick(now=time.perf_counter())
-        if plan is None:
-            return []
-        tracer.complete_span("schedule", tick_t0)
-        # COW copies queued by this tick's admissions land BEFORE the step
-        # (prefill may overwrite the copy from the divergence point); the
-        # pinned source page is released once the copy is issued — ops on
-        # the pools are ordered by data dependency, re-allocation can only
-        # happen at the next host-side tick
-        for src, dst in self.scheduler.drain_copies():
-            self.pools = self._copy_page(self.pools, jnp.int32(src),
-                                         jnp.int32(dst))
-            self.allocator.free([src])
-        self.tick_widths.add(plan.width)
-        n_tok = int(plan.n_tokens.sum())
-        with tracer.span("step", width=plan.width, tokens=n_tok):
-            self._rng, sub = jax.random.split(self._rng)
-            step_args = (self.params, self.pools, jnp.asarray(plan.tokens),
-                         jnp.asarray(self.scheduler.page_table()),
-                         jnp.asarray(plan.start_pos),
-                         jnp.asarray(plan.n_tokens), sub)
-            if self.profiler is not None:
-                sampled, _, self.pools = self.profiler.call(
-                    "engine/tick_step", self._step, *step_args)
-            else:
+        """Run one tick; returns the requests that finished during it.
+
+        Each phase is a span (``obs.trace.Span``): a profiler annotation,
+        and a Chrome event when a ``Tracer`` is attached. ``engine.tick``
+        holds ``engine.schedule``, ``engine.prepare`` (COW page copies,
+        the rng split, the uploads of the plan and the page table),
+        ``engine.dispatch`` (the jitted step's call), ``engine.sync`` (the
+        host waits for the sampled tokens) and ``engine.bookkeep``."""
+        span = self.tracer.span
+        self._tick_no += 1
+        with span("engine.tick", tick=self._tick_no) as tick:
+            with span("engine.schedule"):
+                plan = self.scheduler.next_tick(now=time.perf_counter())
+            if plan is None:
+                return []
+            n_tok = int(plan.n_tokens.sum())
+            tick.set(width=plan.width, tokens=n_tok)
+            self.tick_widths.add(plan.width)
+            with span("engine.prepare"):
+                # COW copies queued by this tick's admissions land BEFORE
+                # the step (prefill may overwrite the copy from the
+                # divergence point); the pinned source page is released
+                # once the copy is issued — ops on the pools are ordered by
+                # data dependency, re-allocation can only happen at the
+                # next host-side tick
+                for src, dst in self.scheduler.drain_copies():
+                    self.pools = self._copy_page(self.pools, jnp.int32(src),
+                                                 jnp.int32(dst))
+                    self.allocator.free([src])
+                self._rng, sub = jax.random.split(self._rng)
+                step_args = (self.params, self.pools,
+                             jnp.asarray(plan.tokens),
+                             jnp.asarray(self.scheduler.page_table()),
+                             jnp.asarray(plan.start_pos),
+                             jnp.asarray(plan.n_tokens), sub)
+            with span("engine.dispatch"):
                 sampled, _, self.pools = self._step(*step_args)
-            sampled = np.asarray(sampled)   # device sync lands in the span
+            with span("engine.sync"):
+                sampled = np.asarray(sampled)
+            with span("engine.bookkeep"):
+                finished = self._bookkeep(plan, sampled, n_tok)
+        return finished
+
+    def _bookkeep(self, plan, sampled, n_tok: int) -> list[dict]:
+        """Registry observes, the scheduler's ``complete_tick`` (stream
+        callbacks included) and zeroing of vacated recurrent slots."""
         self._m_ticks.inc(width=str(plan.width))
         self._m_tick_tokens.observe(n_tok)
         self._m_sampler_batch.observe(len(plan.samples))
         if self.has_attn:
             self._m_occupancy.observe(
                 self.allocator.n_pages - 1 - self.allocator.n_free)
-        with tracer.span("bookkeep"):
-            finished = self.scheduler.complete_tick(
-                plan, sampled, now=time.perf_counter())
-            if self._zero_slots is not None:
-                # zero the recurrent state of slots vacated this tick
-                # (finish or preemption) unless a new occupant landed
-                # already — the in-step position-0 reset covers that
-                # occupant regardless
-                mask = np.zeros(self.config.max_batch, bool)
-                for i in self.scheduler.drain_freed_slots():
-                    mask[i] = self.scheduler.slots[i] is None
-                if mask.any():
-                    self.pools = self._zero_slots(self.pools,
-                                                  jnp.asarray(mask))
+        finished = self.scheduler.complete_tick(plan, sampled,
+                                                now=time.perf_counter())
+        if self._zero_slots is not None:
+            # zero the recurrent state of slots vacated this tick (finish
+            # or preemption) unless a new occupant landed already — the
+            # in-step position-0 reset covers that occupant regardless
+            mask = np.zeros(self.config.max_batch, bool)
+            for i in self.scheduler.drain_freed_slots():
+                mask[i] = self.scheduler.slots[i] is None
+            if mask.any():
+                self.pools = self._zero_slots(self.pools, jnp.asarray(mask))
         for r in finished:
             self._m_finished.inc(request_class=str(r["priority"]))
             self._m_gen_tokens.inc(r["n_generated"])
-        tracer.complete_span("tick", tick_t0, width=plan.width, tokens=n_tok)
         return finished
 
     def run(self, requests=None) -> dict:

@@ -43,7 +43,8 @@ def make_loss_fn(model, aux_weight: float = 1e-2,
             params = param_transform(params)
         if not loss_seq_chunk or batch["labels"].shape[1] <= loss_seq_chunk:
             logits, aux = model.apply_train(params, batch)
-            loss = next_token_loss(logits, batch["labels"])
+            with jax.named_scope("loss"):
+                loss = next_token_loss(logits, batch["labels"])
         else:
             hidden, aux = model.apply_hidden(params, batch)
             b, s = batch["labels"].shape
@@ -55,7 +56,8 @@ def make_loss_fn(model, aux_weight: float = 1e-2,
             def chunk_loss(carry, xs):
                 h, l = xs
                 logits = model.head(params, h)
-                return carry + next_token_loss(logits, l), None
+                with jax.named_scope("loss"):
+                    return carry + next_token_loss(logits, l), None
 
             body = jax.checkpoint(
                 chunk_loss, policy=jax.checkpoint_policies.nothing_saveable)
@@ -111,8 +113,9 @@ def make_train_step(model, opt: ProxOptimizer,
 
     def train_step(state: TrainState, batch) -> tuple[TrainState, dict]:
         grads, metrics = compute_grads(state.params, batch)
-        new_params, new_opt = opt.update(grads, state.opt_state, state.params,
-                                         mask=state.mask)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = opt.update(grads, state.opt_state,
+                                             state.params, mask=state.mask)
         grad_norm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
                                  for g in jax.tree.leaves(grads)))
         metrics = dict(metrics, grad_norm=grad_norm)

@@ -1,4 +1,4 @@
-"""Telemetry subsystem: metrics registry, request tracer, profiling hooks.
+"""Telemetry subsystem: metrics registry, tracer, engine spans and scopes.
 
 The load-bearing guarantees:
   * percentile helpers: ``pct([])`` is ``None`` — an empty completions list
@@ -12,11 +12,17 @@ The load-bearing guarantees:
   * engine end-to-end: Prometheus counters match scheduler / allocator /
     prefix-cache ground truth, and per-request trace span durations
     reconcile with the stats dict's ttft/latency percentiles (± a tick),
-  * profiler: eager kernel calls are wall-timed, traced (in-jit) calls are
-    only counted; the disabled path (no active profiler, NULL registry,
-    NULL tracer) stays no-op cheap.
+  * engine spans: a ``jax.profiler`` trace of the engine holds each tick's
+    phases in order (and ``launch/serve --profile DIR`` writes one), the
+    Chrome export of the same run keeps its engine track, and a span with
+    no profiler session stays cheap,
+  * model scopes: the lowered steps carry them in their ``op_name``
+    metadata, and they change no compiled operation.
 """
+import contextlib
+import glob
 import json
+import re
 import time
 
 import jax
@@ -27,8 +33,7 @@ import pytest
 from repro.models.model_zoo import build
 from repro.obs.metrics import (MetricsRegistry, NULL_REGISTRY, parse_prometheus,
                                pct, prom_value, slo_summary)
-from repro.obs.profile import (Profiler, TrainTelemetry, kernel_call,
-                               sparsity_telemetry_fn)
+from repro.obs.profile import TrainTelemetry, sparsity_telemetry_fn
 from repro.obs.trace import (ENGINE_TID, NULL_TRACER, Tracer,
                              validate_chrome_trace)
 from repro.serve import api
@@ -274,18 +279,21 @@ def test_tracer_preempt_resumed_chain_not_duplicate():
 def test_tracer_engine_span_nesting():
     clock, advance = _fake_clock()
     tr = Tracer(clock=clock)
-    t0 = tr.now_us()
-    advance(0.002)
-    tr.complete_span("schedule", t0)
-    with tr.span("step", width=4):
-        advance(0.003)
-    tr.complete_span("tick", t0, width=4)
+    with tr.span("engine.tick", tick=1) as tick:
+        with tr.span("engine.schedule"):
+            advance(0.002)
+        tick.set(width=4, tokens=3)
+        with tr.span("engine.dispatch"):
+            advance(0.003)
     doc = tr.to_chrome()
     validate_chrome_trace(doc)
     body = [e for e in doc["traceEvents"] if e["ph"] != "M"]
     # parent (longest) first at equal ts; children contained in the tick
-    assert body[0]["name"] == "tick"
+    assert body[0]["name"] == "engine.tick"
     tick = body[0]
+    assert tick["args"] == {"tick": 1, "width": 4, "tokens": 3}
+    assert [e["name"] for e in body[1:]] == ["engine.schedule",
+                                             "engine.dispatch"]
     for child in body[1:]:
         assert tick["ts"] <= child["ts"]
         assert child["ts"] + child["dur"] <= tick["ts"] + tick["dur"]
@@ -332,28 +340,8 @@ def test_null_tracer_inert():
 
 
 # ---------------------------------------------------------------------------
-# profiler + train telemetry
+# train telemetry
 # ---------------------------------------------------------------------------
-
-def test_kernel_call_passthrough_when_inactive():
-    assert kernel_call("t/id", lambda x: x + 1, 41) == 42
-
-
-def test_profiler_times_eager_counts_traced():
-    def f(x):
-        return kernel_call("t/f", jnp.sin, x)
-
-    with Profiler() as p:
-        kernel_call("t/f", jnp.sin, jnp.ones((4,)))       # eager: timed
-        jax.jit(f)(jnp.ones((4,)))                        # traced: counted
-    r = p.summary()["t/f"]
-    assert r["n_calls"] == 1 and r["total_ms"] > 0 and r["mean_ms"] > 0
-    assert r["n_traced"] == 1
-    assert "t/f" in p.format_summary()
-    # deactivated on exit
-    assert kernel_call("t/f", lambda: 7) == 7
-    assert p.summary()["t/f"]["n_calls"] == 1
-
 
 def test_train_telemetry_jsonl(tmp_path):
     path = str(tmp_path / "run.jsonl")
@@ -428,7 +416,7 @@ def test_engine_counters_match_ground_truth(model, params):
         if e["ph"] == "i" and e.get("cat") == "request":
             by_rid_inst.setdefault(e["args"]["rid"], {})[e["name"]] = e["ts"]
     tick_durs = [e["dur"] for e in doc["traceEvents"]
-                 if e["ph"] == "X" and e["name"] == "tick"]
+                 if e["ph"] == "X" and e["name"] == "engine.tick"]
     tol_s = (max(tick_durs) / 1e6) * 1.5 + 0.05   # ± a tick (+ sched slack)
     lats = [(inst["done"] - inst["submit"]) / 1e6
             for inst in by_rid_inst.values()]
@@ -534,3 +522,135 @@ def test_disabled_telemetry_overhead():
         NULL_REGISTRY.counter("x").inc()
     dt = time.perf_counter() - t0
     assert dt < 2.0                            # generous: ~µs per call pair
+
+
+def test_span_without_profiler_session_is_cheap():
+    """An engine span with no profiler session and no Tracer is a TraceMe
+    enter/exit: 200k of them fit the budget of the no-op paths above."""
+    n = 200_000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with NULL_TRACER.span("engine.sync"):
+            pass
+    dt = time.perf_counter() - t0
+    assert dt < 2.0
+
+
+# ---------------------------------------------------------------------------
+# engine spans in a jax.profiler trace; model scopes in the compiled steps
+# ---------------------------------------------------------------------------
+
+PHASES = ["engine.schedule", "engine.prepare", "engine.dispatch",
+          "engine.sync", "engine.bookkeep"]
+
+
+def _host_events(trace_dir):
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    return [ev for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:")
+            for line in p.lines for ev in line.events]
+
+
+def test_engine_ticks_in_profiler_trace(model, params, tmp_path):
+    tracer = Tracer()
+    eng = ServeEngine(model, params,
+                      EngineConfig(max_batch=2, prefill_chunk=8, page_size=4,
+                                   max_seq_len=32), tracer=tracer)
+    eng.submit(api.Request(prompt=_prompts([5], model.cfg.vocab)[0],
+                           max_new_tokens=GEN))
+    eng.step()                                 # compile outside the trace
+    eng.step()
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(3):
+            eng.step()
+    evs = [ev for ev in _host_events(tmp_path)
+           if ev.name.startswith("engine.")]
+    ticks = sorted((ev for ev in evs if ev.name == "engine.tick"),
+                   key=lambda ev: ev.start_ns)
+    assert len(ticks) == 3
+    for tick in ticks:
+        end = tick.start_ns + tick.duration_ns
+        kids = sorted((ev for ev in evs if ev.name != "engine.tick"
+                       and tick.start_ns <= ev.start_ns
+                       and ev.start_ns + ev.duration_ns <= end),
+                      key=lambda ev: ev.start_ns)
+        assert [ev.name for ev in kids] == PHASES
+        for a, b in zip(kids, kids[1:]):
+            assert a.start_ns + a.duration_ns <= b.start_ns
+        args = dict(tick.stats)
+        assert args["width"] == 1 and args["tokens"] == 1
+    assert [dict(t.stats)["tick"] for t in ticks] == [3, 4, 5]
+    # the Chrome export of the same run still has the engine track
+    doc = tracer.to_chrome()
+    validate_chrome_trace(doc)
+    names = [e["name"] for e in doc["traceEvents"]
+             if e["ph"] == "X" and e["tid"] == ENGINE_TID]
+    assert names.count("engine.tick") == 5
+    for phase in PHASES:
+        assert names.count(phase) == 5
+
+
+def test_serve_profile_dir_holds_engine_spans(tmp_path):
+    from repro.launch import serve
+    serve.main(["--arch", "smollm-360m", "--reduced", "--engine",
+                "--batch", "2", "--prompt-len", "4", "--gen", "3",
+                "--max-batch", "2", "--prefill-chunk", "8",
+                "--profile", str(tmp_path)])
+    names = {ev.name for ev in _host_events(tmp_path)}
+    assert {"engine.tick", *PHASES} <= names
+
+
+def _scopes(op_names) -> set:
+    return {part for n in op_names for part in re.split(r"[/()]", n)}
+
+
+def _paged_step(model, params):
+    eng = ServeEngine(model, params,
+                      EngineConfig(max_batch=2, prefill_chunk=8, page_size=4,
+                                   max_seq_len=16))
+    return eng.lower_step(8)
+
+
+def _train_step(model, params):
+    from repro.core.masks import zero_mask
+    from repro.core.optimizers import prox_adam
+    from repro.train.state import TrainState
+    from repro.train.step import make_train_step
+    opt = prox_adam(1e-3, lam=0.0)
+    state = TrainState(params=params, opt_state=opt.init(params),
+                       mask=zero_mask(params), step=jnp.zeros((), jnp.int32))
+    batch = {"inputs": jnp.zeros((2, 16), jnp.int32),
+             "labels": jnp.zeros((2, 16), jnp.int32)}
+    return jax.jit(make_train_step(model, opt)).lower(state, batch)
+
+
+STEPS = {"paged": (_paged_step, {"embed", "layers", "attention", "mlp",
+                                 "head", "sample"}),
+         "train": (_train_step, {"embed", "layers", "attention", "mlp",
+                                 "head", "loss", "optimizer"})}
+
+
+@pytest.mark.parametrize("step", sorted(STEPS))
+def test_lowered_steps_carry_scopes(model, params, step):
+    lower, want = STEPS[step]
+    text = lower(model, params).as_text(debug_info=True)
+    assert want <= _scopes(re.findall(r'loc\("([^"]*)"', text))
+
+
+def _instructions(compiled_text: str) -> list[str]:
+    """The compiled module's instructions with their metadata removed (the
+    module's tables of source locations are no instructions)."""
+    return [re.sub(r", metadata=\{[^}]*\}", "", line).strip()
+            for line in compiled_text.splitlines() if " = " in line]
+
+
+@pytest.mark.parametrize("step", sorted(STEPS))
+def test_scopes_change_no_compiled_op(model, params, step, monkeypatch):
+    lower, _ = STEPS[step]
+    scoped = _instructions(lower(model, params).compile().as_text())
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _instructions(lower(model, params).compile().as_text())
+    assert len(scoped) == len(plain) > 100
+    assert scoped == plain
