@@ -3,8 +3,9 @@ cross-split combine.
 
 ``paged_flash_attention`` is the serving engine's pallas-backend attention
 (``models/attention.paged_attention`` dispatches here when the resolved
-backend is 'pallas'): the kernel walks each slot's page table page by page
-(the gathered ``(B, P*page_size, ...)`` context is never materialized) and
+backend is 'pallas'): the kernel walks each slot's page table a block of
+pages at a time, every KV head per step, up to the slot's own length (the
+gathered ``(B, P*page_size, ...)`` context is never materialized) and
 emits per-KV-split UNNORMALIZED partials (acc, m, l); this wrapper runs
 the flash-decode combine
 

@@ -8,6 +8,10 @@ jnp gather-the-whole-pool reference, across
     inactive slots (0 tokens, page table all trash) in one tick,
   * trash-page rows (invalid tokens write/read page 0 harmlessly),
   * the flash-decode KV-split combine identity (1 split == N splits),
+  * multi-page blocks at shapes where the kernel derives 16 pages a step:
+    ragged and idle slots, lengths off the block grid, a block running
+    past the table, window floors inside a block, split lanes, 256- and
+    512-row prefill chunks, bf16 and int8 pools,
 
 plus the jaxpr-level guarantee that the pallas backend of
 ``models/attention.paged_attention`` never materializes the gathered
@@ -26,6 +30,7 @@ import pytest
 
 from repro.configs.base import ModelConfig
 from repro.kernels.paged_attention import ops as paged_ops
+from repro.kernels.paged_attention import paged_attention as paged_kernel
 from repro.kernels.paged_attention import ref as paged_ref
 from repro.models import attention
 from repro.models.model_zoo import build
@@ -98,6 +103,123 @@ def test_kv_split_combine_identity(window):
                                                interpret=True)
         err = np.abs(np.asarray(one) - np.asarray(many))[valid].max()
         assert err < 1e-5, (splits, err)
+
+
+# -- multi-page blocks --------------------------------------------------------
+#
+# At KV 4, hd 128, page 16 and f32 pools one page of every head is 64 KiB,
+# so the block the kernel derives is 16 pages (256 tokens): a 40-page
+# (640-token) table is three blocks, the last overhanging the table by 8
+# pages. Slots are placed so that lengths fall inside, at the edge of and
+# past block boundaries.
+
+BLK_KV, BLK_G, BLK_HD, BLK_PS, BLK_P = 4, 2, 128, 16, 40
+FULL = BLK_P * BLK_PS                      # 640 tokens: the whole table
+
+
+def _blocked(rng, starts, n_tok, c, dtype=jnp.float32):
+    b, h = len(starts), BLK_KV * BLK_G
+    n_pages = 1 + b * BLK_P
+    shape = (n_pages, BLK_KV, BLK_PS, BLK_HD)
+    q = jnp.asarray(rng.normal(size=(b, c, h, BLK_HD)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=shape), dtype)
+    vp = jnp.asarray(rng.normal(size=shape), dtype)
+    table = 1 + rng.permutation(b * BLK_P).astype(np.int32).reshape(b, BLK_P)
+    n_tok = np.asarray(n_tok)
+    table[n_tok == 0] = 0                  # idle slot: every entry is trash
+    starts = np.asarray(starts, np.int32)
+    positions = jnp.asarray(starts[:, None] + np.arange(c)[None], jnp.int32)
+    valid = np.arange(c)[None, :] < n_tok[:, None]
+    return q, kp, vp, jnp.asarray(table), positions, valid
+
+
+def test_block_is_many_pages_at_these_shapes():
+    """The cases below test multi-page blocks only if the derivation gives
+    them; at the long-context cell's shapes it gives 16 pages (256 tokens,
+    1 MiB of K+V a step) for the decode tick and the 256-token chunk."""
+    ppb = paged_kernel.pages_per_block
+    assert ppb(1 * BLK_G, BLK_KV, BLK_PS, BLK_HD, 4, BLK_P) == 16
+    assert ppb(128 * BLK_G, BLK_KV, BLK_PS, BLK_HD, 4, BLK_P) == 16
+    assert ppb(1 * BLK_G, BLK_KV, BLK_PS, BLK_HD, 4, BLK_P, 4) == 10
+    assert ppb(2, 8, 16, 128, 2, 256) == 16
+    assert ppb(512, 8, 16, 128, 2, 256) == 16
+
+
+@pytest.mark.parametrize("case", [
+    # decode tick, ragged: an idle slot, lengths off the block grid, the
+    # last position of a block, the first of the next, the full table
+    dict(starts=(0, 37, 255, 256, 300, FULL - 1),
+         n_tok=(0, 1, 1, 1, 1, 1), c=1),
+    # a partial chunk whose rows straddle two blocks, and the last block
+    # running past P (keys 640..767 do not exist)
+    dict(starts=(250, 600, 0), n_tok=(12, 9, 0), c=16),
+    # sliding-window floors inside a block (201 in block 0, 301 in block 1)
+    dict(starts=(300, 600, 130, FULL - 1), n_tok=(1, 1, 1, 1), c=1,
+         window=100),
+    dict(starts=(600, 500, 0), n_tok=(1, 8, 0), c=8, window=300),
+    # flash-decode lanes over multi-page blocks (16, 14 and 10 pages)
+    dict(starts=(0, 255, 300, FULL - 1), n_tok=(1, 1, 1, 1), c=1,
+         kv_splits=2),
+    dict(starts=(0, 255, 300, FULL - 1), n_tok=(1, 1, 1, 1), c=1,
+         kv_splits=3, window=200),
+    dict(starts=(40, 511, 512, FULL - 1), n_tok=(1, 1, 1, 1), c=1,
+         kv_splits=4),
+    # a 128-token prefill chunk (256 rows at g 2) and the cell's 256-token
+    # chunk (512 rows), beside a decode slot and an idle one
+    dict(starts=(200, 500, FULL - 1, 0), n_tok=(128, 100, 1, 0), c=128),
+    dict(starts=(0, 384, FULL - 1, 0), n_tok=(256, 256, 1, 0), c=256),
+    # bf16 pools, as served
+    dict(starts=(0, 37, 300, FULL - 1), n_tok=(0, 1, 1, 1), c=1,
+         dtype=jnp.bfloat16),
+], ids=["decode-ragged", "chunk-past-P", "window-decode", "window-chunk",
+        "splits2", "splits3-window", "splits4", "prefill-256-rows",
+        "prefill-512-rows", "bf16-pools"])
+def test_multi_page_blocks_match_ref(case):
+    case = dict(case)
+    window, splits = case.pop("window", None), case.pop("kv_splits", 1)
+    rng = np.random.default_rng(5)
+    q, kp, vp, table, positions, valid = _blocked(rng, **case)
+    out = paged_ops.paged_flash_attention(q, kp, vp, table, positions,
+                                          window=window, kv_splits=splits,
+                                          interpret=True)
+    with jax.default_matmul_precision("highest"):
+        ref = paged_ref.paged_attention_ref(q, kp, vp, table, positions,
+                                            window=window)
+    err = np.abs(np.asarray(out) - np.asarray(ref))[valid].max()
+    assert err < 1e-4, err
+    assert np.isfinite(np.asarray(out)).all()
+
+
+def test_int8_pools_multi_page_blocks():
+    """int8 pools: the layer dequantizes the codes before the kernel, as
+    served; with multi-page blocks the pallas backend still matches the
+    gather-then-softmax reference backend on the same pools."""
+    cfg = _tiny_cfg(d_model=64, n_heads=BLK_KV * BLK_G, n_kv_heads=BLK_KV,
+                    head_dim=BLK_HD, kv_cache_dtype="int8")
+    rng = np.random.default_rng(6)
+    b, c = 3, 4
+    n_pages = 1 + b * BLK_P
+    p = attention.init_attention(jax.random.PRNGKey(2), cfg)
+    x = jnp.asarray(rng.normal(size=(b, c, cfg.d_model)) * 0.1, jnp.float32)
+    shape = (n_pages, BLK_KV, BLK_PS, BLK_HD)
+    cache = {name: jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+             for name in ("k", "v")}
+    for name in ("k_scale", "v_scale"):
+        cache[name] = jnp.asarray(
+            rng.uniform(0.001, 0.02, size=shape[:3] + (1,)), jnp.float32)
+    table = jnp.asarray(
+        1 + rng.permutation(b * BLK_P).astype(np.int32).reshape(b, BLK_P))
+    starts = np.asarray([255, 500, FULL - c], np.int32)
+    positions = jnp.asarray(starts[:, None] + np.arange(c)[None], jnp.int32)
+    n_tokens = jnp.asarray([4, 1, 4], np.int32)
+
+    y_ref, _ = attention.paged_attention(
+        p, x, cache, table, positions, n_tokens, cfg, backend="ref")
+    y_pal, _ = attention.paged_attention(
+        p, x, cache, table, positions, n_tokens, cfg, backend="pallas")
+    valid = np.arange(c)[None, :] < np.asarray(n_tokens)[:, None]
+    err = np.abs(np.asarray(y_ref) - np.asarray(y_pal))[valid].max()
+    assert err < 1e-4, err
 
 
 # -- models/attention dispatch ----------------------------------------------

@@ -112,18 +112,29 @@ def test_bcsr_kernels_compile_for_v5e(one_chip, no_cache, kernel):
             x, w, interpret=False), x, pw)
 
 
-@pytest.mark.parametrize("chunk", [1, 32])
-def test_paged_attention_compiles_for_v5e(one_chip, no_cache, chunk):
-    """Decode (C=1) and a prefill chunk (C=32) over bf16 head-major pools:
-    8 slots of 544 tokens (a 512-token prompt + 32 new), page 16."""
-    b, ps, max_seq = 8, 16, 544
+@pytest.mark.parametrize("arch,slots,max_seq,chunk", [
+    pytest.param("smollm-360m", 8, 544, 1, id="1"),
+    pytest.param("smollm-360m", 8, 544, 32, id="32"),
+    pytest.param("qwen3-0.6b", 16, 4096, 1, id="qwen3-0.6b-1"),
+    pytest.param("qwen3-0.6b", 16, 4096, 256, id="qwen3-0.6b-256"),
+])
+def test_paged_attention_compiles_for_v5e(one_chip, no_cache, arch, slots,
+                                          max_seq, chunk):
+    """Decode (C=1) and a prefill chunk over bf16 head-major pools, page
+    16: smollm-360m (hd 64, g 3) with 8 slots of 544 tokens (a 512-token
+    prompt + 32 new), and the long-context cell's qwen3-0.6b (KV 8, hd
+    128, g 2) with 16 slots of 4096 tokens (P = 256) at the decode tick
+    and the 256-token prefill chunk. The compile proves the page block
+    derived from these shapes fits VMEM at both widths."""
+    cfg = get_config(arch)
+    ps = 16
     p_log = -(-max_seq // ps)
     pool = jax.eval_shape(
-        lambda: init_paged_kv(CFG, b * p_log + 1, ps, jnp.bfloat16))
-    q = jnp.zeros((b, chunk, CFG.n_heads, CFG.resolved_head_dim),
+        lambda: init_paged_kv(cfg, slots * p_log + 1, ps, jnp.bfloat16))
+    q = jnp.zeros((slots, chunk, cfg.n_heads, cfg.resolved_head_dim),
                   jnp.bfloat16)
-    table = jnp.zeros((b, p_log), jnp.int32)
-    positions = jnp.zeros((b, chunk), jnp.int32)
+    table = jnp.zeros((slots, p_log), jnp.int32)
+    positions = jnp.zeros((slots, chunk), jnp.int32)
     _compile(one_chip, lambda q, k, v, t, p: paged_ops.paged_flash_attention(
         q, k, v, t, p, interpret=False), q, pool["k"], pool["v"], table,
         positions)
