@@ -1,25 +1,34 @@
-"""The comparison that decides ``correct``: served tokens against the
-plain reference.
+"""The comparison that decides ``correct``: what the timed path produced
+against the plain reference of the configuration's architecture.
 
-Once the window has closed and the program's state is freed, a sample of
-the requests the window served, drawn from the seed and always holding the
-one with the most served tokens, is run through the reference
-(``reference/llama.py``) once per request: the prompt followed by the served
-tokens, teacher-forced. At each served position the reference's logits give
-the gap by which the served token lies below the reference's best token;
+The architecture's module (``bench/arch/<architecture>.py``, found by the
+configuration's ``"architecture"``) gives both sides of the comparison:
+the seeded weights, which the program's compression took at set-up and the
+reference takes again here, and the plain reference
+(``bench/reference/<architecture>.py``), which imports nothing of the
+program. Nothing here depends on the architecture.
+
+Serving: once the window has closed and the program's state is freed, a
+sample of the requests the window served, drawn from the seed and always
+holding the one with the most served tokens, is run through the reference
+once per request: the prompt followed by the served tokens,
+teacher-forced. At each served position the reference's logits give the
+gap by which the served token lies below the reference's best token;
 greedy decoding that agrees with the reference up to rounding keeps it
 near 0. Two numbers come of it, each compared with the configuration's
 limit: the widest gap and the mean gap over the sample's served tokens.
-
 The control (``quant="fp8"``) reads, at the same positions, the gap of the
 token that the reference in float8 puts first.
+
+Training: the reference's first Adam steps on the same batches
+(``reference/adam.py``), compared leaf by leaf with the program's
+trainable leaves, whatever layer groups hold them.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from bench import weights as W
-from bench.reference import llama
+from bench import arch as arch_lib
 
 
 def sample_requests(served, seed: int, target_tokens) -> list[int]:
@@ -50,7 +59,8 @@ def logit_gaps(cfg: dict, seed: int, served, picked, max_seq_len: int,
     control."""
     import jax
     import jax.numpy as jnp
-    weights = W.make_weights_fn(cfg)(W.seed_key(seed))
+    arch = arch_lib.of(cfg)
+    weights = arch.weights(cfg, seed)
     widest = {"": 0.0, "control_": 0.0}
     total = {"": 0.0, "control_": 0.0}
     n_tok = 0
@@ -68,11 +78,11 @@ def logit_gaps(cfg: dict, seed: int, served, picked, max_seq_len: int,
         at = np.full(max_out, p - 1, np.int32)
         at[:n] = np.arange(p - 1, p - 1 + n)
         with jax.default_matmul_precision("highest"):
-            ref = llama.logits_at(weights, cfg, seq, p + n, at)[:n]
+            ref = arch.reference.logits_at(weights, cfg, seq, p + n, at)[:n]
             best = jnp.max(ref, axis=-1)
             picks = {"": jnp.asarray(toks, jnp.int32)}
             if control:
-                picks["control_"] = jnp.argmax(llama.logits_at(
+                picks["control_"] = jnp.argmax(arch.reference.logits_at(
                     weights, cfg, seq, p + n, at, quant="fp8")[:n], axis=-1)
             for key, pick in picks.items():
                 g = gaps_of(ref, best, pick)
@@ -90,7 +100,9 @@ def logit_gaps(cfg: dict, seed: int, served, picked, max_seq_len: int,
 
 def _ref_name(name: str) -> tuple[str, bool]:
     """The reference leaf of a program trainable leaf, and whether the two
-    hold the same elements in another layout (a BCSR block store)."""
+    hold the same elements in another layout (a BCSR block store). A block
+    store is keyed by its path in the tree, joined by ``/``
+    (``['bcsr_data']['layers/<group>/attn/wq']``), under any group."""
     if name.startswith("['bcsr_data']"):
         path = name[len("['bcsr_data']['"):-2].split("/")
         return "".join(f"['{p}']" for p in path), True
@@ -115,15 +127,16 @@ def reference_steps(cfg: dict, mix: dict, seed: int, batches,
     per leaf the first gradient's norm and the change's norm."""
     import jax
     from bench.reference import adam
+    arch = arch_lib.of(cfg)
     z = float(mix["loss"]["z_weight"])
 
     def grad_fn(w, b):
         with jax.default_matmul_precision("highest"):
-            return llama.loss_and_grad(w, cfg, b["inputs"], b["labels"], z,
-                                       quant=quant)
+            return arch.reference.loss_and_grad(
+                w, cfg, b["inputs"], b["labels"], z, quant=quant)
 
-    return adam.steps(W.make_weights_fn(cfg)(W.seed_key(seed)), grad_fn,
-                      batches, float(mix["optimizer"]["lr"]))
+    return adam.steps(arch.weights(cfg, seed), grad_fn, batches,
+                      float(mix["optimizer"]["lr"]))
 
 
 def train_gaps(losses, leaves: dict, ref: dict) -> dict:

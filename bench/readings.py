@@ -2,29 +2,29 @@
 
 Each reader gets a context: the run (its recorded tick plans, registry
 snapshots at the window's open and close, the stored-block counts read
-from the compressed params at set-up), the reduced trace and the chip's
-peaks. These helpers turn those into kernel work, roofline shares and
-registry deltas; a reader returns None where there is nothing to read.
+from the compressed params at set-up, and its architecture's module), the
+reduced trace and the chip's peaks. These helpers turn those into kernel
+work, roofline shares and registry deltas; a reader returns None where
+there is nothing to read. The projections and the layers that call paged
+attention are the architecture's (``bench/arch/``).
 """
 from __future__ import annotations
 
 import numpy as np
 
-from bench import weights as W
 from bench.work import bsr_sddmm, bsr_spmm, paged_flash_attention
 
 
-def stored_blocks(cp) -> dict:
+def stored_blocks(cp, projections: dict) -> dict:
     """{projection: blocks per layer} from the compressed params: the slots
     the BCSR gather tables reference (pad slots excluded)."""
-    layer = cp.sparse["layers"]["b0_attn"]
-    names = {("attn", n): n for n in ("wq", "wk", "wv", "wo")}
-    names.update({("mlp", "wi"): "wi", ("mlp", "wg"): "wg",
-                  ("mlp", "wo"): "mlp_wo"})
     out = {}
-    for (group, leaf), proj in names.items():
-        nnz = np.asarray(layer[group][leaf].gather_nnz)     # (L, R)
-        out[proj] = nnz.reshape(nnz.shape[0], -1).sum(axis=1)
+    for name, p in projections.items():
+        leaf = cp.sparse
+        for key in p.path:
+            leaf = leaf[key]
+        nnz = np.asarray(leaf.gather_nnz)     # (layers, ..., R)
+        out[name] = nnz.reshape(p.layers, -1).sum(axis=1)
     return out
 
 
@@ -42,29 +42,32 @@ def roofline_pct(calls, device_s: float, peak: dict) -> float | None:
 def serve_spmm_calls(run) -> list:
     """(FLOPs, bytes) of every ``bsr_spmm`` call of the window's ticks."""
     cfg = run.cfg
-    projs = W.projections(cfg)
+    projs = run.arch.projections(cfg)
     block = tuple(cfg["compression"]["block"])
     calls = []
     for _, _, n_tok in run.plans:
         rows = int(np.asarray(n_tok).sum())
         if rows == 0:
             continue
-        for proj, (_, _, out, inp) in projs.items():
+        for proj, p in projs.items():
             for blocks in run.blocks[proj]:
-                calls.append(bsr_spmm.work(rows, int(blocks), block, inp,
-                                           out, x_bytes=2, w_bytes=4))
+                calls.append(bsr_spmm.work(rows, int(blocks), block, p.inp,
+                                           p.out, x_bytes=2, w_bytes=4))
     return calls
 
 
 def serve_attention_calls(run) -> list:
-    """(FLOPs, bytes) of every ``paged_flash_attention`` call."""
-    s = W.sizes(run.cfg)
+    """(FLOPs, bytes) of every ``paged_flash_attention`` call: one per
+    layer that calls it, each tick."""
+    geometry = run.arch.attention(run.cfg)
     calls = []
     for _, start, n_tok in run.plans:
-        one = paged_flash_attention.work(start, n_tok, s["H"], s["KV"],
-                                         s["hd"], kv_bytes=2)
-        if one[0] > 0:
-            calls.extend([one] * s["L"])
+        for a in geometry:
+            one = paged_flash_attention.work(start, n_tok, a.heads,
+                                             a.kv_heads, a.hd, kv_bytes=2,
+                                             window=a.window)
+            if one[0] > 0:
+                calls.extend([one] * a.layers)
     return calls
 
 
@@ -95,7 +98,7 @@ def train_calls(run, trace: dict) -> dict:
     Operands are bfloat16 (the step casts weights and activations to the
     compute dtype); outputs float32."""
     cfg, mix = run.cfg, run.mix
-    projs = W.projections(cfg)
+    projs = run.arch.projections(cfg)
     block = tuple(cfg["compression"]["block"])
     rows = int(mix["batch"]) * int(mix["seq_len"])
     steps = len(run.done_times)
@@ -103,11 +106,11 @@ def train_calls(run, trace: dict) -> dict:
     n_spmm = trace["kernel_calls"].get("bsr_spmm", 0)
     fwd = max(round(n_spmm / max(steps * per_step, 1)) - 1, 1)
     spmm, sddmm = [], []
-    for proj, (_, _, out, inp) in projs.items():
+    for proj, p in projs.items():
         for blocks in run.blocks[proj]:
             b = int(blocks)
-            f = bsr_spmm.work(rows, b, block, inp, out, 2, 2)
-            t = bsr_spmm.work(rows, b, block, out, inp, 2, 2)
+            f = bsr_spmm.work(rows, b, block, p.inp, p.out, 2, 2)
+            t = bsr_spmm.work(rows, b, block, p.out, p.inp, 2, 2)
             spmm.extend([f] * fwd + [t])
-            sddmm.append(bsr_sddmm.work(rows, b, block, out, inp, 2, 2))
+            sddmm.append(bsr_sddmm.work(rows, b, block, p.out, p.inp, 2, 2))
     return {"bsr_spmm": spmm * steps, "bsr_sddmm": sddmm * steps}

@@ -3,12 +3,13 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Finds the cell in ``BENCHMARK.json``, its configuration under
-``bench/configs/`` and its traffic mix under ``bench/traffic/`` by name,
-builds the program's system under test from them and the seed (set-up),
-measures for ``--seconds`` (the window), checks what the window produced
-against the plain reference, and prints one JSON line last on standard
-output. ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1``
-records a profiler trace of the window and reports its per-layer metrics.
+``bench/configs/``, the configuration's architecture under ``bench/arch/``
+and its traffic mix under ``bench/traffic/`` by name, builds the program's
+system under test from them and the seed (set-up), measures for
+``--seconds`` (the window), checks what the window produced against the
+plain reference, and prints one JSON line last on standard output.
+``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` records
+a profiler trace of the window and reports its per-layer metrics.
 
 Runs only on a TPU: with no accelerator, or fewer chips than the cell
 asks for, it exits non-zero and prints no result.
@@ -36,7 +37,9 @@ def load_json(path: Path) -> dict:
 
 
 def load_cell(name: str) -> tuple[dict, dict, dict, dict]:
-    """(benchmark spec, workload entry, configuration, traffic mix)."""
+    """(benchmark spec, workload entry, configuration, traffic mix). A
+    configuration whose architecture has no module under ``bench/arch/``
+    exits here, before any device work."""
     spec = load_json(CHECKOUT / "BENCHMARK.json")
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
@@ -44,6 +47,8 @@ def load_cell(name: str) -> tuple[dict, dict, dict, dict]:
     cell = cells[name]
     confs = {c["name"]: c for c in spec["configs"]}
     cfg = load_json(CHECKOUT / confs[cell["config"]]["file"])
+    from bench import arch
+    arch.of(cfg)
     mix = load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
     return spec, cell, cfg, mix
 
@@ -122,9 +127,9 @@ def main(argv=None) -> int:
     args = parse_args(argv)
     if args.seed < 0:
         raise SystemExit("--seed must be >= 0")
-    spec, cell, cfg, mix = load_cell(args.workload)
     sys.path.insert(0, str(CHECKOUT / "src"))
     sys.path.insert(0, str(CHECKOUT))
+    spec, cell, cfg, mix = load_cell(args.workload)
     from bench import cell as cell_lib
     peaks = load_json(BENCH / "peaks.json")
     enable_compile_cache()

@@ -1,10 +1,11 @@
 """Serving cells: the program's continuous-batching engine under a mix.
 
 Set-up builds the system under test from the configuration and the seed:
-block-sparse weights made on the device (``weights.py``), the program's own
-``compress_params`` (BCSR at the configuration's block), a ``ServeEngine``
-with the mix's engine settings, and one warm-up request per compiled tick
-width. A closed-loop mix also opens its sessions and prefills them here.
+the architecture's seeded block-sparse weights (``bench/arch/``), the
+program's own ``compress_params`` (BCSR at the configuration's block), a
+``ServeEngine`` with the mix's engine settings, and one warm-up request per
+compiled tick width. A closed-loop mix also opens its sessions and
+prefills them here.
 
 The window then drives ``ServeEngine.submit`` / ``ServeEngine.step`` for
 exactly ``seconds``. Token times come from the engine's stream callback on
@@ -17,7 +18,7 @@ import time
 
 import numpy as np
 
-from bench import loadgen, readings
+from bench import arch as arch_lib, loadgen, readings
 
 
 class _Client:
@@ -40,32 +41,13 @@ class _Client:
         self.done = bool(ev.done)
 
 
-def program_model(cfg: dict, remat: bool = False):
-    """The program's ModelConfig and Model for a benchmark configuration."""
-    from repro.configs.base import ModelConfig
-    from repro.models.transformer import make_model
-    m = cfg["model"]
-    mcfg = ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"],
-        head_dim=m.get("head_dim"), d_ff=m["intermediate_size"],
-        vocab=m["vocab_size"], qk_norm=bool(cfg.get("qk_norm")),
-        rope_theta=float(m["rope_theta"]),
-        tie_embeddings=bool(m["tie_word_embeddings"]), norm="rmsnorm",
-        mlp_gated=True, act=m["hidden_act"],
-        compute_dtype=cfg["compute_dtype"], param_dtype="float32")
-    return mcfg, make_model(mcfg, remat=remat)
-
-
-def compressed_params(cfg: dict, seed: int, model):
-    """Seeded block-sparse weights through the program's compression."""
+def compressed_params(arch, cfg: dict, seed: int, model):
+    """Seeded block-sparse weights through the program's compression,
+    which has to compress exactly the architecture's projections."""
     import jax
     from repro.sparse.compress import CompressionPlan, compress_params
-    from bench import weights as W
 
-    dense = W.make_weights_fn(cfg)(W.seed_key(seed))
+    dense = arch.weights(cfg, seed)
     want = jax.tree.map(lambda a: (a.shape, a.dtype),
                         jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     got = jax.tree.map(lambda a: (a.shape, a.dtype), dense)
@@ -75,13 +57,29 @@ def compressed_params(cfg: dict, seed: int, model):
     plan = CompressionPlan(block=tuple(cfg["compression"]["block"]))
     cp = compress_params(dense, plan)
     del dense
-    layer = cp.sparse.get("layers", {}).get("b0_attn", {})
-    have = {(g, n) for g in ("attn", "mlp") for n in layer.get(g, {})}
-    need = {(g, n) for g, n, _, _ in W.projections(cfg).values()}
-    if have != need:
-        raise RuntimeError(f"the program left {sorted(need - have)} dense; "
-                           "the configuration compresses every projection")
+    projs = arch.projections(cfg)
+    have = dict(_leaves(cp.sparse))
+    need = {p.path for p in projs.values()}
+    if set(have) != need:
+        raise RuntimeError(
+            f"the program left {sorted(need - set(have))} dense and "
+            f"compressed {sorted(set(have) - need)}; the configuration "
+            "compresses exactly its projections")
+    for name, p in projs.items():
+        if have[p.path].gather_nnz.shape[0] != p.layers:
+            raise RuntimeError(f"{name} is stacked over "
+                               f"{have[p.path].gather_nnz.shape[0]} layers, "
+                               f"not {p.layers}")
     return cp
+
+
+def _leaves(tree, path=()):
+    """(path, leaf) of every leaf of a tree of nested dicts."""
+    if not isinstance(tree, dict):
+        yield path, tree
+        return
+    for k, v in tree.items():
+        yield from _leaves(v, path + (k,))
 
 
 class ServeRun:
@@ -90,8 +88,9 @@ class ServeRun:
     def __init__(self, cfg: dict, mix: dict, seed: int, seconds: float):
         self.cfg, self.mix, self.seed = cfg, mix, int(seed)
         self.seconds = float(seconds)
+        self.arch = arch_lib.of(cfg)
         self.eng_cfg = loadgen.engine_sizes(mix)
-        self.vocab = cfg["model"]["vocab_size"]
+        self.vocab = self.arch.vocab(cfg)
         self.plans: list[tuple] = []       # (width, start_pos, n_tokens)
         self.clients: list[_Client] = []
 
@@ -99,9 +98,11 @@ class ServeRun:
 
     def setup(self):
         from repro.serve.engine import EngineConfig, ServeEngine
-        _, model = program_model(self.cfg)
-        self.params = compressed_params(self.cfg, self.seed, model)
-        self.blocks = readings.stored_blocks(self.params)
+        _, model = self.arch.program_model(self.cfg)
+        self.params = compressed_params(self.arch, self.cfg, self.seed,
+                                        model)
+        self.blocks = readings.stored_blocks(self.params,
+                                             self.arch.projections(self.cfg))
         e = self.eng_cfg
         self.engine = ServeEngine(model, self.params, EngineConfig(
             max_batch=e["max_batch"], prefill_chunk=e["prefill_chunk"],

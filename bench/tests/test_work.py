@@ -57,20 +57,40 @@ def test_model_train_step():
     assert f == 6 * 6 * 110 + 3 * 4 * 3 * 4 * 2 * 2 * 6
 
 
+def test_windowed_attention_hand_count():
+    # window 4: slot 0 decodes at position 9 (4 keys, K/V of 6..9); slot 1
+    # prefills positions 2..5 (3 + 4 + 4 + 4 keys, K/V of 0..5)
+    f, b = paged_flash_attention.work([9, 2], [1, 4], heads=4, kv_heads=2,
+                                      hd=8, kv_bytes=2, window=4)
+    assert f == 4 * 4 * 8 * (4 + 15)
+    assert b == 2 * (4 + 6) * 2 * 8 * 2 + (1 + 4) * 4 * 8 * (4 + 4)
+    assert model.attention_keys([9, 2], [1, 4], window=4) == 4 + 15
+    # a window wider than every context counts as none
+    assert paged_flash_attention.work([9, 2], [1, 4], 4, 2, 8, 2,
+                                      window=64) == \
+        paged_flash_attention.work([9, 2], [1, 4], 4, 2, 8, 2)
 
 
 def test_blocks_come_from_the_compressed_params():
     import jax
-    from bench.serve import compressed_params, program_model
+    from bench import arch
+    from bench.serve import compressed_params
     cfg = tiny.cfg()
-    _, m = program_model(cfg)
-    cp = compressed_params(cfg, 5, m)
-    got = readings.stored_blocks(cp)
-    want = W.stored_blocks(cfg)
+    a = arch.of(cfg)
+    _, m = a.program_model(cfg)
+    cp = compressed_params(a, cfg, 5, m)
+    projs = a.projections(cfg)
+    got = readings.stored_blocks(cp, projs)
+    comp = cfg["compression"]
     for proj, per_layer in got.items():
-        assert list(per_layer) == [want[proj]] * 2, proj
-        leaf = W.projections(cfg)[proj]
-        data = cp.sparse["layers"]["b0_attn"][leaf[0]][leaf[1]].data
+        p = projs[proj]
+        want = W.kept_blocks(p.out, p.inp, tuple(comp["block"]),
+                             comp["sparsity"])[2]
+        assert list(per_layer) == [want] * 2, proj
+        leaf = cp.sparse
+        for key in p.path:
+            leaf = leaf[key]
         # stored slots beyond the count are zero padding (pad slot 0 too)
-        nz = np.asarray(jax.device_get(data)).reshape(2, data.shape[1], -1)
-        assert list((np.abs(nz).sum(-1) > 0).sum(-1)) == [want[proj]] * 2
+        nz = np.asarray(jax.device_get(leaf.data)).reshape(
+            2, leaf.data.shape[1], -1)
+        assert list((np.abs(nz).sum(-1) > 0).sum(-1)) == [want] * 2

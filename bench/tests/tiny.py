@@ -7,7 +7,8 @@ BENCH = Path(__file__).resolve().parents[1]
 
 
 def cfg(limits=None):
-    c = {"name": "tiny", "qk_norm": False, "compute_dtype": "bfloat16",
+    c = {"name": "tiny", "architecture": "llama", "qk_norm": False,
+         "compute_dtype": "bfloat16",
          "model": {"hidden_size": 128, "intermediate_size": 256,
                    "num_hidden_layers": 2, "num_attention_heads": 4,
                    "num_key_value_heads": 2, "head_dim": 32,

@@ -20,8 +20,8 @@ from __future__ import annotations
 import math
 import time
 
-from bench import loadgen, readings
-from bench.serve import compressed_params, program_model
+from bench import arch as arch_lib, loadgen, readings
+from bench.serve import compressed_params
 
 CHECK_STEPS = 3
 
@@ -43,7 +43,8 @@ class TrainRun:
     def __init__(self, cfg: dict, mix: dict, seed: int, seconds: float):
         self.cfg, self.mix, self.seed = cfg, mix, int(seed)
         self.seconds = float(seconds)
-        self.vocab = cfg["model"]["vocab_size"]
+        self.arch = arch_lib.of(cfg)
+        self.vocab = self.arch.vocab(cfg)
         self.step_no = 0
         self.done_times: list[float] = []
         self.failed = 0                     # steps with a non-finite loss
@@ -63,9 +64,10 @@ class TrainRun:
         from repro.train.state import TrainState
         from repro.train.step import make_train_step
 
-        _, model = program_model(self.cfg, remat=True)
-        cp = compressed_params(self.cfg, self.seed, model)
-        self.blocks = readings.stored_blocks(cp)
+        _, model = self.arch.program_model(self.cfg, remat=True)
+        cp = compressed_params(self.arch, self.cfg, self.seed, model)
+        self.blocks = readings.stored_blocks(cp,
+                                             self.arch.projections(self.cfg))
         trainable, rebuild = split_trainable(cp)
         del cp
         opt_cfg = self.mix["optimizer"]
@@ -141,7 +143,8 @@ class TrainRun:
                 for k in range(CHECK_STEPS)]
 
     def check(self) -> dict:
-        """The first three steps against the reference (float32, Adam)."""
+        """The first three steps against the architecture's reference
+        (float32, Adam)."""
         import gc
         import sys
         import jax

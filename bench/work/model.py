@@ -10,12 +10,21 @@ padded rows and idle slots do not count.
 import numpy as np
 
 
-def attention_keys(start_pos, n_tokens) -> int:
+def attention_keys(start_pos, n_tokens, window=None) -> int:
     """Keys met by all live query tokens (a token at position p meets
-    p + 1 keys)."""
+    p + 1 keys, or ``window`` at most)."""
     start = np.asarray(start_pos, np.int64)
     n = np.asarray(n_tokens, np.int64)
-    return int((n * start + n * (n + 1) // 2).sum())
+    return int((_keys_below(start + n, window)
+                - _keys_below(start, window)).sum())
+
+
+def _keys_below(m, window):
+    """Keys met by the query tokens at positions 0 .. m - 1."""
+    if window is None:
+        return m * (m + 1) // 2
+    w = np.minimum(m, window)
+    return w * (w + 1) // 2 + (m - w) * window
 
 
 def serve_tick(start_pos, n_tokens, stored_weights: int, head_weights: int,
